@@ -26,9 +26,9 @@ Two paged-specific sections:
   engine, whose monolithic long prefills stall the admission step.
 
 A ``mesh`` axis reports tensor-parallel serving throughput (contiguous and
-paged) at each of ``MESH_SHAPES`` device counts — each shape runs in a
-subprocess with ``--xla_force_host_platform_device_count`` because this
-process's jax is already initialized single-device.
+paged) at each of ``MESH_SHAPES`` device counts that this process has
+devices for (on a CPU host, start it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 
 ``python benchmarks/serve_throughput.py`` writes ``BENCH_serve.json``;
 ``--smoke`` shrinks the model and stream for CI.
@@ -248,43 +248,28 @@ def _mesh_args(full: bool):
     return params, cfg, scfg, prompts, budgets
 
 
-def _mesh_one(full: bool, n: int) -> dict:
-    """Subprocess entry: the bench stream served tensor-parallel over an
-    n-device ("model",) mesh — contiguous and paged.  Runs out-of-process
-    because multi-device CPU needs XLA_FLAGS set before jax initializes."""
+def _bench_mesh(full: bool) -> dict:
+    """The bench stream served tensor-parallel over each ("model",) mesh
+    width this process's devices allow — contiguous and paged, all in this
+    one process (a chip belongs to the process that first touches it)."""
+    import jax
     from repro.dist import tp
     from repro.launch.mesh import mesh_for
     params, cfg, scfg, prompts, budgets = _mesh_args(full)
-    mesh = mesh_for((n,), ("model",))
-    ok, reason = tp.tp_eligible(cfg, n)
-    out = {"devices": n, "tp_path": "shard_map" if ok else "gspmd",
-           "tp_reason": reason}
-    out["continuous"] = _run_continuous(params, cfg, scfg, prompts, budgets,
-                                        mesh=mesh)
-    out["paged"] = _run_paged(params, cfg, scfg, prompts, budgets, mesh=mesh)
-    return out
-
-
-def _bench_mesh(full: bool) -> dict:
-    """Fan the mesh shapes out to subprocesses (this process's jax is
-    already initialized single-device); one JSON line back per shape."""
-    import os
-    import subprocess
-    import sys
     out = {}
     for n in MESH_SHAPES:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        cmd = [sys.executable, os.path.abspath(__file__),
-               "--_mesh-one", str(n)]
-        if not full:
-            cmd.append("--smoke")
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=3600)
-        if r.returncode != 0:
-            out[f"mesh{n}"] = {"error": r.stderr[-1000:]}
+        if n > len(jax.devices()):
+            out[f"mesh{n}"] = {"skipped": f"{len(jax.devices())} devices"}
             continue
-        out[f"mesh{n}"] = json.loads(r.stdout.strip().splitlines()[-1])
+        mesh = mesh_for((n,), ("model",))
+        ok, reason = tp.tp_eligible(cfg, n)
+        out[f"mesh{n}"] = {
+            "devices": n, "tp_path": "shard_map" if ok else "gspmd",
+            "tp_reason": reason,
+            "continuous": _run_continuous(params, cfg, scfg, prompts,
+                                          budgets, mesh=mesh),
+            "paged": _run_paged(params, cfg, scfg, prompts, budgets,
+                                mesh=mesh)}
     return out
 
 
@@ -373,12 +358,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny model + short stream (CI)")
     ap.add_argument("--out", default="BENCH_serve.json")
-    ap.add_argument("--_mesh-one", type=int, default=0, dest="mesh_one",
-                    help=argparse.SUPPRESS)   # internal subprocess entry
     args = ap.parse_args()
-    if args.mesh_one:
-        print(json.dumps(_mesh_one(not args.smoke, args.mesh_one)))
-        return
     res = bench(full=not args.smoke)
     with open(args.out, "w") as f:
         json.dump(res, f, indent=1, sort_keys=True)
@@ -400,8 +380,8 @@ def main() -> None:
           f"{res['ttft_mixed']['contiguous']['short_ttft_p99_ms']}ms "
           f"(no_worse={res['ttft_mixed']['paged_no_worse']})")
     for key, m in sorted(res["mesh"].items()):
-        if "error" in m:
-            print(f"{key}: FAILED ({m['error'][:200]})")
+        if "skipped" in m:
+            print(f"{key}: skipped ({m['skipped']})")
         else:
             print(f"{key} ({m['tp_path']}): continuous "
                   f"{m['continuous']['tokens_per_s']} tok/s, paged "

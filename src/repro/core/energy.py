@@ -8,9 +8,8 @@ for logging/parity with the paper.
 Two energy backends:
 
 * :class:`CostModelEnergy` — the two-pipe TPU latency simulator
-  (:mod:`repro.core.costmodel`).  Deterministic, instant, and meaningful for
-  the TPU target even inside this CPU-only container (DESIGN.md §2 records
-  why this deviation from the paper is justified on TPU).
+  (:mod:`repro.core.costmodel`).  Deterministic, instant, and available
+  without a chip; an estimate, never a measurement.
 * :class:`WallClockEnergy` — compile-and-measure, the paper's choice.  Used
   for the paper-dynamics reproduction and wherever a real device exists.
 
@@ -25,6 +24,7 @@ import dataclasses
 import time
 from typing import Any, Callable, MutableSet, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import costmodel
@@ -60,8 +60,12 @@ class WallClockEnergy:
     """Energy from measured execution (CUDA-events analogue: timed jit calls).
 
     ``build(schedule)`` returns a callable taking ``*args``; ``make_args()``
-    returns the positional inputs.  We warm up (compile + cache) then take the
-    median of ``iters`` timed calls, blocking on the result.
+    returns the positional inputs, which are placed on the device before the
+    clock starts (the energy is the kernel's time, not a host-to-device copy
+    per call).  We warm up (compile + cache) then take the median of
+    ``iters`` timed calls, blocking on the result.  A schedule that fails to
+    build, compile or run scores FAILED: the search proposes such schedules
+    by design; ``SipKernel.tune`` holds the start schedule to running.
     """
 
     build: Callable[[Schedule], Callable[..., Any]]
@@ -72,35 +76,17 @@ class WallClockEnergy:
     def __call__(self, schedule: Schedule) -> float:
         try:
             fn = self.build(schedule)
-            args = self.make_args()
+            args = jax.device_put(list(self.make_args()))
             for _ in range(self.warmup):
-                _block(fn(*args))
+                jax.block_until_ready(fn(*args))
             times = []
             for _ in range(self.iters):
                 t0 = time.perf_counter()
-                out = fn(*args)
-                _block(out)
+                jax.block_until_ready(fn(*args))
                 times.append(time.perf_counter() - t0)
             return float(np.median(times))
         except Exception:
             return FAILED   # unassemblable schedule (paper: cuasm failure)
-
-
-def _block(out: Any) -> None:
-    for leaf in _leaves(out):
-        if hasattr(leaf, "block_until_ready"):
-            leaf.block_until_ready()
-
-
-def _leaves(x: Any):
-    if isinstance(x, (list, tuple)):
-        for v in x:
-            yield from _leaves(v)
-    elif isinstance(x, dict):
-        for v in x.values():
-            yield from _leaves(v)
-    else:
-        yield x
 
 
 class CachedEnergy:

@@ -19,6 +19,7 @@ import dataclasses
 import json
 from typing import Any, Callable, MutableSet, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import annealing, energy as energy_mod, population, testing
@@ -259,6 +260,14 @@ class SipKernel:
             knobs = dict(space.default_knobs())
             knobs.update(x0.knobs)
             x0 = dataclasses.replace(x0, knobs=knobs)
+        # the start schedule is what an untuned call runs: one the compiler
+        # refuses is a broken kernel, not a poor candidate, so its error is
+        # raised here instead of scoring FAILED as a mutated schedule does
+        try:
+            jax.block_until_ready(built(x0)(*example_args))
+        except Exception as e:
+            raise RuntimeError(f"{self.name}: start schedule {x0.signature()} "
+                               f"does not run at {sig}") from e
 
         results = []
         for r in range(config.rounds):

@@ -15,8 +15,8 @@ meshes:
   serving path: explicit per-layer allreduce seams that can run the
   compressed collective, where GSPMD could only place exact psums.
 
-:mod:`repro.dist.compat` papers over jax API drift (``jax.shard_map`` vs
-``jax.experimental.shard_map``) so callers never branch on version.
+:mod:`repro.dist.compat` is ``jax.shard_map`` under the one name the
+repository calls.
 """
 
 from repro.dist import collectives, partition, pipeline, tp

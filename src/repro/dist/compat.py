@@ -1,11 +1,7 @@
-"""jax API drift shims.
+"""``jax.shard_map`` under the one name the repository calls.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and its replication-check kwarg was renamed ``check_rep`` -> ``check_vma``
-along the way.  Everything in this repo (and its test subprocesses) goes
-through this wrapper so the call sites are written against the new spelling
-only.
-"""
+Every call site (and the multi-device test scripts) goes through this
+wrapper, so the replication-check argument is spelled in one place."""
 
 from __future__ import annotations
 
@@ -16,16 +12,6 @@ import jax
 
 def shard_map(f: Callable[..., Any], mesh, in_specs, out_specs,
               check_vma: bool = True):
-    """``jax.shard_map`` with the modern signature on any supported jax."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError as e:  # top-level API but pre-rename kwarg
-            if "check_vma" not in str(e):
-                raise
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with keyword arguments."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -14,6 +14,16 @@ import importlib
 import importlib.util
 import pkgutil
 
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether a Pallas kernel called now runs in interpret mode: everywhere
+    but on a TPU.  Kernels ask at call time (never at import), so importing a
+    kernel module touches no backend."""
+    return jax.default_backend() != "tpu"
+
+
 # integration modules probed inside each kernel package, in import order
 _INTEGRATION_MODULES = ("ops", "pallas_ops")
 

@@ -30,8 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ir import Instr, Kind, Program
+from repro.kernels import interpret_mode
 
-INTERPRET = jax.default_backend() != "tpu"
 NEG_INF = -1e30
 
 
@@ -179,8 +179,10 @@ def make_program(*, bq: int, bk: int, n_chunks: int, d: int, sq: int, skv: int,
 def pallas_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      bq: int, bk: int, n_chunks: int = 1,
                      causal: bool = True, window: int | None = None,
-                     order=None, interpret: bool = INTERPRET) -> jax.Array:
+                     order=None, interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D)."""
+    if interpret is None:
+        interpret = interpret_mode()
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     assert hq % hkv == 0 and sq % bq == 0 and skv % bk == 0

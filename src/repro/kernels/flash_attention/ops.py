@@ -23,17 +23,26 @@ from repro.kernels.flash_attention import kernel as K
 from repro.kernels.flash_attention import ref
 
 
-def _choices(dim: int, prefs: tuple[int, ...]) -> tuple[int, ...]:
-    ch = tuple(c for c in prefs if dim % c == 0 and c <= dim)
+def _choices(dim: int, prefs: tuple[int, ...], sublanes: int) -> tuple[int, ...]:
+    """Tiles of ``dim`` the TPU lowering accepts as a block's second-minor
+    extent: multiples of the dtype's sublane tiling that divide ``dim``, or
+    else the whole dimension."""
+    ch = tuple(c for c in prefs
+               if c <= dim and dim % c == 0 and c % sublanes == 0)
     return ch or (dim,)
 
 
 def space(*, b, hq, hkv, sq, skv, d, causal, window, dtype="float32") -> SearchSpace:
-    bks = _choices(skv, (256, 512, 128, 64, 32, 16, 8))
+    # 8 rows of 32-bit words per vreg; narrower dtypes pack 32 // bits rows
+    sublanes = 8 * (4 // jnp.dtype(dtype).itemsize)
+    prefs = (256, 512, 128, 64, 32, 16, 8)
+    bks = _choices(skv, prefs, sublanes)
     return SearchSpace(knobs=(
-        KnobSpec("bq", _choices(sq, (256, 512, 128, 64, 32, 16, 8, 1))),
+        KnobSpec("bq", _choices(sq, prefs, sublanes)),
         KnobSpec("bk", bks),
-        KnobSpec("n_chunks", tuple(c for c in (2, 4, 1) if bks[0] % c == 0)),
+        # kv chunks are row slices of the kv block: each must stay tiled too
+        KnobSpec("n_chunks", tuple(c for c in (2, 4, 1)
+                                   if c == 1 or bks[0] % (c * sublanes) == 0)),
     ))
 
 

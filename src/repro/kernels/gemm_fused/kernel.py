@@ -27,8 +27,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ir import Instr, Kind, Program
+from repro.kernels import interpret_mode
 
-INTERPRET = jax.default_backend() != "tpu"
 ALPHA = 0.01
 
 
@@ -88,7 +88,9 @@ def make_program(*, m: int, n: int, k: int, bm: int, bn: int, bk: int,
 
 def pallas_gemm_leaky_relu(x: jax.Array, w: jax.Array, *, bm: int, bn: int,
                            bk: int, order=None,
-                           interpret: bool = INTERPRET) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
+    if interpret is None:
+        interpret = interpret_mode()
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)

@@ -26,8 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ir import Instr, Kind, Program
-
-INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels import interpret_mode
 
 
 def make_program(*, ps: int, h: int, d: int, rows: int, n_chunks: int,
@@ -64,8 +63,10 @@ def make_program(*, ps: int, h: int, d: int, rows: int, n_chunks: int,
 
 def paged_gather(store: jax.Array, page_table: jax.Array, *,
                  rows: int, n_chunks: int, order=None,
-                 interpret: bool = INTERPRET) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """store: (P, ps, H, D); page_table: (B, n) int32 -> (B, n, ps, H, D)."""
+    if interpret is None:
+        interpret = interpret_mode()
     p, ps, h, d = store.shape
     b, n = page_table.shape
     program = make_program(ps=ps, h=h, d=d, rows=rows, n_chunks=n_chunks,

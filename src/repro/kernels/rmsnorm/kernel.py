@@ -18,8 +18,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ir import Instr, Kind, Program
+from repro.kernels import interpret_mode
 
-INTERPRET = jax.default_backend() != "tpu"
 EPS = 1e-6
 
 
@@ -82,7 +82,9 @@ def make_program(*, br: int, d: int, n_chunks: int, dtype=jnp.float32,
 
 def pallas_rmsnorm(x: jax.Array, gamma: jax.Array, *, br: int,
                    n_chunks: int = 1, order=None,
-                   interpret: bool = INTERPRET) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
+    if interpret is None:
+        interpret = interpret_mode()
     rows, d = x.shape
     assert rows % br == 0 and gamma.shape == (d,)
     program = make_program(br=br, d=d, n_chunks=n_chunks, dtype=x.dtype)

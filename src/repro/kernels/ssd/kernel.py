@@ -21,8 +21,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ir import Instr, Kind, Program
-
-INTERPRET = jax.default_backend() != "tpu"
+from repro.kernels import interpret_mode
 
 
 def make_program(*, q: int, n: int, p: int, dtype=jnp.float32,
@@ -81,9 +80,11 @@ def make_program(*, q: int, n: int, p: int, dtype=jnp.float32,
 
 def pallas_ssd_intra(xb: jax.Array, la: jax.Array, B: jax.Array,
                      C: jax.Array, *, order=None,
-                     interpret: bool = INTERPRET) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """Intra-chunk SSD.  xb: (G, Q, H, P) dt-weighted inputs; la: (G, Q, H)
     log-decays; B, C: (G, Q, N).  G = batch*chunks.  Returns (G, Q, H, P)."""
+    if interpret is None:
+        interpret = interpret_mode()
     g, q, h, p = xb.shape
     n = B.shape[-1]
     program = make_program(q=q, n=n, p=p, dtype=xb.dtype)

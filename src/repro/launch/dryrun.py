@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the production mesh (16x16 single-pod / 2x16x16
@@ -20,6 +17,7 @@ import argparse
 import functools
 import json
 import math
+import os
 import re
 import time
 from typing import Any
@@ -386,4 +384,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # the production meshes are placeholder host devices; the flag must be in
+    # place before the first backend use, and is added to the caller's flags
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
     main()

@@ -60,10 +60,9 @@ import numpy as np
 
 from repro import configs, obs
 from repro.core.registry import schedule_cache
-from repro.models import model as M
-from repro.models import modules as nn
+from repro.launch.jax_cache import enable_compile_cache
 from repro.serve.engine import (ContinuousEngine, Engine, ServeConfig,
-                                static_batches)
+                                init_params, static_batches)
 
 
 @dataclasses.dataclass
@@ -251,6 +250,7 @@ def main() -> None:
     if args.compressed_collectives and not args.mesh:
         ap.error("--compressed-collectives requires --mesh")
 
+    enable_compile_cache()
     mesh = None
     if args.mesh:
         from repro.launch.mesh import mesh_for
@@ -259,7 +259,8 @@ def main() -> None:
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     if args.use_pallas:
         cfg = dataclasses.replace(cfg, use_pallas=True)
-    params = nn.unwrap(M.init_lm(jax.random.PRNGKey(0), cfg))
+    # with a mesh, parameters are made in their serving shards
+    params = init_params(jax.random.PRNGKey(0), cfg, mesh, args.tp_mode)
     rng = np.random.default_rng(args.seed)
     traffic = make_traffic(args, rng)
     # global maxima, not max(plen_i + new_i): a static batch left-pads to its

@@ -30,6 +30,7 @@ from repro.data.pipeline import DataConfig
 from repro.ft import (ChaosEngine, FaultPlan, FTConfig, FTManager,
                       RestartBudgetExhausted, Supervisor, SupervisorConfig)
 from repro.launch import mesh as mesh_lib
+from repro.launch.jax_cache import enable_compile_cache
 from repro.optim import adamw
 from repro.train.loop import TrainConfig, train
 
@@ -66,6 +67,7 @@ def main(argv: list[str] | None = None) -> int:
                     metavar="S")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     mcfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     dcfg = DataConfig(global_batch=args.batch, seq_len=args.seq,
                       vocab=mcfg.vocab)

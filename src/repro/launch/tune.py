@@ -22,6 +22,7 @@ import dataclasses
 from repro import kernels, obs
 from repro.core.jit import TuneConfig
 from repro.core.registry import registry
+from repro.launch.jax_cache import enable_compile_cache
 from repro.tuning.session import SimulatedCrash, TuningSession
 from repro.tuning.state import state_path_for
 
@@ -105,6 +106,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="write a metrics-registry snapshot of the run")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     kernels.load_all()
     if args.list:
         _print_listing()

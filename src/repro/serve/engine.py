@@ -66,6 +66,7 @@ from repro.core.registry import active_schedule_cache
 from repro.dist import partition, tp
 from repro.dist.compat import shard_map
 from repro.models import model as M
+from repro.models import modules as nn
 from repro.models.config import ModelConfig
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -110,6 +111,55 @@ class ServeConfig:
                                     # decode-seam psums (shard_map path only;
                                     # bounded error, NOT token-exact)
     compress_block: int = 64        # quantization block for compressed seams
+
+
+def resolve_tp_path(cfg: ModelConfig, mesh, tp_mode: str = "auto",
+                    compressed: bool = False) -> tuple[str, str]:
+    """Pick the sharded execution path for serving ``cfg`` on ``mesh`` per
+    ``tp_mode`` (see :mod:`repro.dist.tp` for the eligibility rationale).
+    Returns ``(path, reason)``."""
+    if "model" not in mesh.axis_names:
+        raise ValueError(f"serving mesh needs a 'model' axis, got "
+                         f"{mesh.axis_names}")
+    ok, reason = tp.tp_eligible(cfg, mesh.shape["model"])
+    if tp_mode == "shard_map":
+        if not ok:
+            raise ValueError(f"tp_mode='shard_map' but {reason}")
+        path = "shard_map"
+    elif tp_mode == "gspmd":
+        path = "gspmd"
+    elif tp_mode == "auto":
+        path = "shard_map" if ok else "gspmd"
+    else:
+        raise ValueError(f"tp_mode must be 'auto'/'shard_map'/'gspmd', "
+                         f"got {tp_mode!r}")
+    if compressed and path != "shard_map":
+        raise ValueError(f"compressed_collectives needs the shard_map TP "
+                         f"path ({reason})")
+    return path, reason
+
+
+def param_shardings(cfg: ModelConfig, mesh, tp_path: str):
+    """Where a serving engine on ``mesh`` keeps each parameter: the manual
+    TP layout on the shard_map path, ``SERVE_RULES`` on the GSPMD path."""
+    paxes = M.param_logical_axes(cfg)
+    if tp_path == "shard_map":
+        return tp.tp_shardings(paxes, mesh)
+    return partition.tree_shardings(
+        paxes, mesh, sds_tree=nn.unwrap(M.init_lm_shapes(
+            jax.random.PRNGKey(0), cfg)), rules=partition.SERVE_RULES)
+
+
+def init_params(key, cfg: ModelConfig, mesh=None, tp_mode: str = "auto"):
+    """Random serving parameters made in place: on the default device, or
+    with ``mesh`` directly in the shards the engine will serve them from.
+    One jitted init, so no device ever holds a whole sharded model."""
+    def init(k):
+        return nn.unwrap(M.init_lm(k, cfg))
+    if mesh is None:
+        return jax.jit(init)(key)
+    path, _ = resolve_tp_path(cfg, mesh, tp_mode)
+    return jax.jit(init, out_shardings=param_shardings(cfg, mesh, path))(key)
 
 
 class Engine:
@@ -286,7 +336,8 @@ class ContinuousEngine:
         self.tp_path: str | None = None
         self.tp_reason = ""
         if mesh is not None:
-            self.tp_path, self.tp_reason = self._resolve_tp_path()
+            self.tp_path, self.tp_reason = resolve_tp_path(
+                cfg, mesh, scfg.tp_mode, scfg.compressed_collectives)
         elif scfg.compressed_collectives:
             raise ValueError("compressed_collectives requires a serving mesh "
                              "(the seams only exist on the shard_map path)")
@@ -369,31 +420,6 @@ class ContinuousEngine:
         self._last_emit: dict[int, float] = {}   # uid -> last token time
 
     # ------------------------------------------------------- sharded serving
-    def _resolve_tp_path(self) -> tuple[str, str]:
-        """Pick the sharded execution path for ``self.mesh`` per
-        ``scfg.tp_mode`` (see :mod:`repro.dist.tp` for the eligibility
-        rationale).  Returns ``(path, reason)``."""
-        scfg, mesh = self.scfg, self.mesh
-        if "model" not in mesh.axis_names:
-            raise ValueError(f"serving mesh needs a 'model' axis, got "
-                             f"{mesh.axis_names}")
-        ok, reason = tp.tp_eligible(self.cfg, mesh.shape["model"])
-        if scfg.tp_mode == "shard_map":
-            if not ok:
-                raise ValueError(f"tp_mode='shard_map' but {reason}")
-            path = "shard_map"
-        elif scfg.tp_mode == "gspmd":
-            path = "gspmd"
-        elif scfg.tp_mode == "auto":
-            path = "shard_map" if ok else "gspmd"
-        else:
-            raise ValueError(f"tp_mode must be 'auto'/'shard_map'/'gspmd', "
-                             f"got {scfg.tp_mode!r}")
-        if scfg.compressed_collectives and path != "shard_map":
-            raise ValueError(f"compressed_collectives needs the shard_map TP "
-                             f"path ({reason})")
-        return path, reason
-
     def _shard_state(self) -> None:
         """Move params and the freshly allocated slot/page caches onto the
         serving mesh.  Admission never materializes an unsharded cache after
@@ -401,23 +427,19 @@ class ContinuousEngine:
         shardings, and splicing (insert/evict/set_len) runs on the sharded
         buffers in place."""
         mesh, cfg = self.mesh, self.cfg
-        paxes = M.param_logical_axes(cfg)
         caxes = M.serve_cache_axes(cfg, self._axes)
         self._grp_axes = M.cache_logical_axes(cfg)
+        pshard = param_shardings(cfg, mesh, self.tp_path)
         if self.tp_path == "shard_map":
-            self._pspecs = tp.tp_specs(paxes)
+            self._pspecs = tp.tp_specs(M.param_logical_axes(cfg))
             self._cspecs = tp.tp_specs(caxes)
             self._grp_specs = tp.tp_specs(self._grp_axes)
-            pshard = tp.tp_shardings(paxes, mesh)
             cshard = tp.tp_shardings(caxes, mesh)
         else:
-            rules = partition.SERVE_RULES
-            pshard = partition.tree_shardings(paxes, mesh,
-                                              sds_tree=self.params,
-                                              rules=rules)
             cshard = partition.tree_shardings(caxes, mesh,
                                               sds_tree=self.caches,
-                                              rules=rules)
+                                              rules=partition.SERVE_RULES)
+        # a no-op for params made by init_params on this mesh
         self.params = jax.device_put(self.params, pshard)
         self.caches = jax.device_put(self.caches, cshard)
         self._cache_shardings = cshard

@@ -8,12 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist.pipeline import pipeline_apply
+from repro.launch.mesh import mesh_for
 
 N_STAGES, D, N_MICRO, MB = 4, 16, 8, 4
 
 
 def _setup():
-    mesh = jax.make_mesh((N_STAGES, 2), ("stage", "dp"))
+    mesh = mesh_for((N_STAGES, 2), ("stage", "dp"))
     rng = np.random.default_rng(0)
     # n_stages small MLP stages: y = tanh(x @ w + b)
     w = jnp.asarray(rng.standard_normal((N_STAGES, D, D)) * 0.3, jnp.float32)

@@ -15,6 +15,7 @@ import numpy as np
 def train_parity():
     """Sharded train step on a (4, 2) mesh == single-device step."""
     from repro.dist import partition
+    from repro.launch import mesh as mesh_lib
     from repro.launch import steps
     from repro.models import model as M
     from repro.models import modules as nn
@@ -36,7 +37,7 @@ def train_parity():
     p_ref, _, m_ref = steps.train_step(params, opt, batch, cfg=cfg,
                                        opt_cfg=ocfg)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = mesh_lib.mesh_for((4, 2), ("data", "model"))
     with partition.mesh_rules(mesh):
         pshard = steps.param_shardings(ptree, mesh)
         oshard = steps.opt_shardings(pshard, mesh)
@@ -65,8 +66,9 @@ def compressed_psum_test():
     from jax.sharding import PartitionSpec as P
     from repro.dist import collectives
     from repro.dist.compat import shard_map
+    from repro.launch import mesh as mesh_lib
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = mesh_lib.mesh_for((8,), ("pod",))
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((8, 64, 32)), jnp.float32)
 
@@ -220,6 +222,7 @@ def elastic():
     import tempfile
 
     from repro.checkpoint.ckpt import CheckpointManager
+    from repro.launch import mesh as mesh_lib
     from repro.launch import steps
     from repro.models import model as M
     from repro.models import modules as nn
@@ -231,7 +234,7 @@ def elastic():
     ptree = M.init_lm(jax.random.PRNGKey(3), cfg)
     params = nn.unwrap(ptree)
 
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = mesh_lib.mesh_for((4, 2), ("data", "model"))
     shard_a = steps.param_shardings(ptree, mesh_a)
     params_a = jax.device_put(params, shard_a)
 
@@ -240,7 +243,7 @@ def elastic():
         cm.save(1, params_a)
         ok = True
         for shape in ((2, 4), (8, 1), (1, 8)):
-            mesh_b = jax.make_mesh(shape, ("data", "model"))
+            mesh_b = mesh_lib.mesh_for(shape, ("data", "model"))
             shard_b = steps.param_shardings(ptree, mesh_b)
             restored = cm.restore(1, params, shard_b)
             for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):

@@ -6,6 +6,9 @@ equivalence with ref.py — the same contract SIP's probabilistic testing
 enforces at search time.
 """
 
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -93,7 +96,7 @@ class TestFlashAttention:
 
     @pytest.mark.parametrize("b,hq,hkv,s,d", [
         (1, 1, 1, 32, 16), (2, 4, 2, 64, 16), (1, 8, 1, 128, 32),
-        (2, 2, 2, 64, 64)])
+        (2, 2, 2, 64, 64), (1, 2, 1, 37, 16)])
     def test_causal_gqa_shapes(self, b, hq, hkv, s, d):
         q, k, v = self._mk(b, hq, hkv, s, s, d)
         got = np.asarray(fa_ops.flash_attention(q, k, v))
@@ -138,6 +141,23 @@ class TestFlashAttention:
         got = np.asarray(fn(q, k, v))
         want = np.asarray(fa_ref.attention(q, k, v, causal=True))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("sq,dtype,bq", [
+        (37, "bfloat16", (37,)), (48, "bfloat16", (16,)),
+        (48, "float32", (16, 8)), (8, "bfloat16", (8,)),
+        (512, "bfloat16", (256, 512, 128, 64, 32, 16))])
+    def test_space_offers_only_lowerable_tiles(self, sq, dtype, bq):
+        """Second-minor tiles are multiples of the dtype's sublane tiling
+        (8 rows of f32, 16 of bf16) that divide the length, or else the
+        whole length — never a 1-row tile the TPU lowering refuses."""
+        sp = fa_ops.space(b=1, hq=2, hkv=1, sq=sq, skv=sq, d=128,
+                          causal=True, window=None, dtype=dtype)
+        assert sp.knob("bq").choices == bq
+        assert sp.knob("bk").choices == bq
+        sub = 16 if dtype == "bfloat16" else 8
+        for n in sp.knob("n_chunks").choices:
+            ck = sp.knob("bk").choices[0] // n
+            assert n == 1 or ck % sub == 0
 
     def test_all_single_moves_preserve_semantics(self):
         q, k, v = self._mk(1, 2, 1, 32, 32, 16)
@@ -218,3 +238,19 @@ class TestSSD:
             x[:, 32:], dt[:, 32:], A, B[:, 32:], C[:, 32:], D, chunk=16,
             init_state=st))
         np.testing.assert_allclose(y_tail, y_full[:, 32:], rtol=1e-4, atol=1e-4)
+
+
+def test_importing_repro_touches_no_backend():
+    """Every module under ``repro`` imports without initializing a JAX
+    backend: a kernel asks whether to interpret when it is called."""
+    code = ("import importlib, pathlib, repro\n"
+            "for root in repro.__path__:\n"
+            "    for f in sorted(pathlib.Path(root).rglob('*.py')):\n"
+            "        rel = f.relative_to(pathlib.Path(root).parent)\n"
+            "        importlib.import_module('.'.join(rel.with_suffix('')"
+            ".parts).removesuffix('.__init__'))\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, sorted(xla_bridge._backends)\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]

@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from repro.core import ScheduleCache
-from repro.core.jit import TuneConfig
+from repro.core.jit import SipKernel, TuneConfig
+from repro.core.schedule import SearchSpace
 from repro.kernels.gemm_fused import ops as gemm_ops
 from repro.kernels.gemm_fused import ref as gemm_ref
 from repro.kernels.rmsnorm import ops as rms_ops
@@ -72,3 +73,27 @@ class TestSipJitWorkflow:
         ent = kern.cache.entries(rms_ops.NAME,
                                  kern.sig_str(kern.static_of(x, g)))
         assert ent and all(e.tests_passed for e in ent)
+
+
+@pytest.mark.parametrize("quarantine", [None, set()])
+def test_start_schedule_that_does_not_run_raises(quarantine):
+    """A default schedule the compiler refuses is a broken kernel: tuning
+    raises with the compiler's error (even where a crash quarantine would
+    score a mutated schedule FAILED), and so does an untuned call."""
+    def build(schedule, **static):
+        def refused(x):
+            raise RuntimeError("block shape refused by the compiler")
+        return refused
+
+    kern = SipKernel(name="refused", build=build,
+                     program_for=lambda s, **st: None,
+                     space_for=lambda **st: SearchSpace(),
+                     oracle=lambda x: x,
+                     signature_fn=lambda x: {"n": int(x.shape[0])})
+    x = np.ones(8, np.float32)
+    with pytest.raises(RuntimeError, match="start schedule") as err:
+        kern.tune([x], TuneConfig(rounds=1, cooling=2.0, final_samples=1,
+                                  energy="wallclock"), quarantine=quarantine)
+    assert "refused by the compiler" in str(err.value.__cause__)
+    with pytest.raises(RuntimeError, match="refused by the compiler"):
+        kern(x)
