@@ -27,6 +27,7 @@ from repro.core.cache import LRUCache, ScheduleCache
 from repro.core.ir import Program
 from repro.core.mutation import MutationPolicy
 from repro.core.schedule import Schedule, SearchSpace
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 
@@ -186,7 +187,22 @@ class SipKernel:
         seam).  Its knobs must be legal points of THIS signature's search
         space — an incompatible warm start raises instead of silently
         searching the wrong space; a stale order is fine (resolution falls
-        back to the program default when lengths mismatch)."""
+        back to the program default when lengths mismatch).
+
+        Each energy evaluation the memo misses is a ``tune.eval`` span, and
+        the seconds JAX spends tracing, lowering and compiling during the
+        call are added to the active registry's ``tune.compile_s``."""
+        compile0 = obs_trace.compile_seconds()
+        try:
+            return self._tune(example_args, config, verbose,
+                              quarantine=quarantine, x0=x0)
+        finally:
+            obs_metrics.counter("tune.compile_s").inc(
+                obs_trace.compile_seconds() - compile0)
+
+    def _tune(self, example_args: Sequence[Any], config: TuneConfig | None,
+              verbose: bool, *, quarantine: MutableSet[str] | None,
+              x0: Schedule | None) -> list[annealing.AnnealResult]:
         config = TuneConfig() if config is None else config
         config.validate()
         static = self.static_of(*example_args)
@@ -243,6 +259,15 @@ class SipKernel:
                 guarded, deadline_s=config.eval_deadline_s,
                 quarantine=quarantine)
             guarded = quarantine_wrap
+        # inside the memo wrapper: a span is an evaluation the memo missed
+        scored = guarded
+
+        def evaluate(s: Schedule) -> float:
+            with obs_trace.span("tune.eval", kernel=self.name) as sp:
+                sp["energy"] = e = scored(s)
+            return e
+
+        guarded = evaluate
         if config.memoize:
             # shared across all chains AND rounds: revisited schedules are
             # free.  This also freezes each schedule's step-test verdict at

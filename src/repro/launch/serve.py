@@ -116,7 +116,7 @@ def drive_continuous(eng: ContinuousEngine, traffic: list[TrafficSpec],
         eng.step()
     wall = time.perf_counter() - t0
     lat = [r.finished_at - r.submitted_at for r in handles]
-    ttft = [r.admitted_at - r.submitted_at for r in handles]
+    ttft = [r.first_token_at - r.submitted_at for r in handles]
     toks = sum(len(r.tokens) for r in handles)
     # top-level tokens_per_s is WALL-clock (includes arrival idle time) and
     # directly comparable to drive_static's; the engine's busy-time rates

@@ -1,29 +1,37 @@
-"""Structured event tracer with nested spans and a Chrome-trace exporter.
+"""One span API on two sinks: the profiler's timeline and an in-memory tracer.
 
-A :class:`Tracer` collects timestamped events — complete spans (``ph: "X"``),
-instants (``"I"``) and counter samples (``"C"``) — in the Chrome Trace Event
-format, so a tune or serve run saved with ``tracer.save("run.json")`` opens
-directly in ``ui.perfetto.dev`` / ``chrome://tracing``: spans nest by time
-containment per (pid, tid) track, counter tracks plot energy-vs-step or
-queue depth over the run.
+:func:`span` always enters a ``jax.profiler.TraceAnnotation``, so while a
+profiler session runs the span is a host event on the device trace's own
+clock, and idle device time can be named by what the program was doing.
+When a :class:`Tracer` is active (``with tracing(t):``) the same span is
+also recorded there as a complete event (``ph: "X"``) in the Chrome Trace
+Event format, so ``tracer.save("run.json")`` opens in ``ui.perfetto.dev``:
+spans nest by time containment per (pid, tid) track, counter tracks plot
+energy-vs-step or queue depth over the run.  Each span measures its
+duration once (``Span.dur_s``), so callers that keep their own counters
+read it instead of timing the interval again.
+
+With neither sink active a span costs about a microsecond: the annotation
+is a no-op outside a profiler session, and no event is built.  JAX is
+imported on the first span, so ``repro.obs`` imports without it.
+
+The first span also registers one ``jax.monitoring`` listener per process:
+every ``/jax/core/compile/*`` event (tracing, lowering, compiling) is
+counted into the active metrics registry (``jax.compiles``, and in
+``jax.compile_s`` the wall seconds it adds, nested events counted once) and
+emitted as a ``jax.compile`` instant on the active tracer, so a trace shows
+which span compiled.  :func:`compile_seconds` gives the process's total.
 
 Event collection is thread-safe (the serve engine emits from its streaming
-callback thread); an optional streaming JSONL sink writes each event as one
-JSON line the moment it is recorded, so a crashed run still leaves a
-readable trace.  ``save`` writes either the Chrome JSON object
+callback thread).  ``save`` writes either the Chrome JSON object
 (``{"traceEvents": [...]}``, for ``.json`` paths) or JSONL (one event per
 line, anything else); :func:`load_trace` and :func:`validate_events` read
 and schema-check both forms (``launch/obsreport.py --validate``).
-
-Scoping mirrors :mod:`repro.obs.metrics`: ``with tracing(tracer):`` pushes
-the tracer onto a contextvar stack; instrumented code calls the module-level
-:func:`span` / :func:`instant` / :func:`counter` helpers, which are cheap
-no-ops when no tracer is active — tracing disabled must stay off the serve
-hot path.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import json
@@ -32,57 +40,53 @@ import threading
 import time
 from typing import Any, Iterator
 
+from repro.obs import metrics as obs_metrics
+
 #: event fields beyond these are rejected by the validator
 _EVENT_KEYS = {"name", "ph", "ts", "dur", "pid", "tid", "args", "s", "cat"}
 _PHASES = {"X", "I", "C", "M"}
 
 
 class Tracer:
-    """Collects Chrome-trace events; see module docstring."""
+    """Collects Chrome-trace events in memory; see module docstring."""
 
-    def __init__(self, jsonl_path: str | None = None, *,
-                 pid: int | None = None):
+    def __init__(self, *, pid: int | None = None):
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._pid = os.getpid() if pid is None else pid
-        self._file = open(jsonl_path, "w") if jsonl_path else None
 
     # ------------------------------------------------------------ recording
-    def _now_us(self) -> float:
-        return round((time.perf_counter() - self._t0) * 1e6, 3)
+    def _us(self, t: float) -> float:
+        return round((t - self._t0) * 1e6, 3)
 
     def _emit(self, ev: dict) -> None:
         with self._lock:
             self._events.append(ev)
-            if self._file is not None:
-                self._file.write(json.dumps(ev) + "\n")
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[dict]:
-        """Complete-event span: emitted on exit with the measured duration.
+    def complete(self, name: str, t0: float, t1: float,
+                 args: dict[str, Any]) -> None:
+        """A span from ``t0`` to ``t1`` (``time.perf_counter`` seconds)."""
+        ts = self._us(t0)
+        self._emit({"name": name, "ph": "X", "ts": ts,
+                    "dur": round(self._us(t1) - ts, 3), "pid": self._pid,
+                    "tid": threading.get_ident(), "args": _jsonable(args)})
 
-        Yields the mutable ``args`` dict so the body can attach results
-        (``s["tokens"] = n``) that land in the recorded event.
-        """
-        t0 = self._now_us()
-        try:
-            yield args
-        finally:
-            t1 = self._now_us()
-            self._emit({"name": name, "ph": "X", "ts": t0,
-                        "dur": round(t1 - t0, 3), "pid": self._pid,
-                        "tid": threading.get_ident(),
-                        "args": _jsonable(args)})
+    def span(self, name: str, **args: Any) -> "Span":
+        """A span recorded on this tracer (and on the profiler's timeline);
+        ``sp["k"] = v`` in the body attaches a result to the event."""
+        return Span(name, self, args)
 
     def instant(self, name: str, **args: Any) -> None:
-        self._emit({"name": name, "ph": "I", "ts": self._now_us(), "s": "t",
+        self._emit({"name": name, "ph": "I",
+                    "ts": self._us(time.perf_counter()), "s": "t",
                     "pid": self._pid, "tid": threading.get_ident(),
                     "args": _jsonable(args)})
 
     def counter(self, name: str, values: dict[str, float]) -> None:
         """One sample on the counter track ``name`` (plots as a time series)."""
-        self._emit({"name": name, "ph": "C", "ts": self._now_us(),
+        self._emit({"name": name, "ph": "C",
+                    "ts": self._us(time.perf_counter()),
                     "pid": self._pid, "tid": 0, "args": _jsonable(values)})
 
     # -------------------------------------------------------------- export
@@ -103,12 +107,6 @@ class Tracer:
                 for ev in self.events():
                     f.write(json.dumps(ev) + "\n")
 
-    def close(self) -> None:
-        with self._lock:
-            if self._file is not None:
-                self._file.close()
-                self._file = None
-
 
 def _jsonable(d: dict[str, Any]) -> dict[str, Any]:
     out = {}
@@ -122,6 +120,110 @@ def _jsonable(d: dict[str, Any]) -> dict[str, Any]:
         else:
             out[k] = repr(v)
     return out
+
+
+class Span:
+    """One timed region on both sinks (see module docstring).  After exit
+    ``dur_s`` holds its seconds; ``span["k"] = v`` attaches a result to the
+    tracer's event."""
+
+    __slots__ = ("name", "args", "dur_s", "_tracer", "_ann", "_t0")
+
+    def __init__(self, name: str, tracer: Tracer | None,
+                 args: dict[str, Any]):
+        self.name = name
+        self.args = args
+        self.dur_s = 0.0
+        self._tracer = tracer
+        self._ann = None
+
+    def __setitem__(self, key: str, value: Any) -> None:
+        self.args[key] = value
+
+    def __enter__(self) -> "Span":
+        ann = _ANNOTATION or _jax_hooks()
+        if ann:
+            self._ann = ann(self.name, **self.args)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self.dur_s = t1 - self._t0
+        if self._tracer is not None:
+            self._tracer.complete(self.name, self._t0, t1, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+# ----------------------------------------------------------- jax hooks
+#: ``jax.profiler.TraceAnnotation`` once the first span has looked for it;
+#: False where JAX cannot be imported
+_ANNOTATION: Any = None
+_HOOK_LOCK = threading.Lock()
+
+
+class _CompileWatch:
+    """The process's one ``jax.monitoring`` time-span listener (JAX keeps
+    listeners per process).  Compile events nest: tracing a function traces
+    the jitted functions it calls, and each reports its own span.  Events
+    arrive as they end, so one that holds earlier ones adds only the time
+    they left uncovered, and ``seconds`` is the wall time in which JAX was
+    tracing, lowering or compiling."""
+
+    PREFIX = "/jax/core/compile/"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # the latest spans, disjoint and in order of their ends
+        self._spans: collections.deque[tuple[float, float]] = \
+            collections.deque(maxlen=4096)
+        self.seconds = 0.0
+
+    def __call__(self, name: str, start: float, end: float,
+                 **kw: Any) -> None:
+        if not name.startswith(self.PREFIX):
+            return
+        with self._lock:
+            secs = end - start
+            while self._spans and self._spans[-1][0] >= start:
+                s, e = self._spans.pop()
+                secs -= e - s
+            self._spans.append((start, end))
+            self.seconds += secs
+        reg = obs_metrics.active_registry()
+        reg.counter("jax.compiles").inc()
+        reg.counter("jax.compile_s").inc(secs)
+        instant("jax.compile", event=name[len(self.PREFIX):],
+                fun=kw.get("fun_name", ""), s=end - start)
+
+
+_COMPILES: _CompileWatch | None = None
+
+
+def _jax_hooks():
+    """Import ``TraceAnnotation`` and register the compile listener, once."""
+    global _ANNOTATION, _COMPILES
+    with _HOOK_LOCK:
+        if _ANNOTATION is None:
+            try:
+                import jax.monitoring
+                from jax.profiler import TraceAnnotation
+            except ImportError:
+                _ANNOTATION = False
+            else:
+                _COMPILES = _CompileWatch()
+                jax.monitoring.register_event_time_span_listener(_COMPILES)
+                _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def compile_seconds() -> float:
+    """Seconds of JAX tracing, lowering and compiling in this process since
+    the listener was registered (registering it on the first call)."""
+    _jax_hooks()
+    return 0.0 if _COMPILES is None else _COMPILES.seconds
 
 
 # --------------------------------------------------------------- scoping
@@ -146,17 +248,11 @@ def active_tracer() -> Tracer | None:
     return stack[-1] if stack else None
 
 
-@contextlib.contextmanager
-def span(name: str, **args: Any) -> Iterator[dict]:
-    """Module-level span helper: records on the active tracer, no-op
-    (yielding a throwaway dict the body may still write to) when tracing
-    is off."""
-    t = active_tracer()
-    if t is None:
-        yield args
-    else:
-        with t.span(name, **args) as s:
-            yield s
+def span(name: str, **args: Any) -> Span:
+    """A span on the profiler's timeline, recorded on the active tracer
+    too when there is one."""
+    stack = _ACTIVE.get()
+    return Span(name, stack[-1] if stack else None, args)
 
 
 def instant(name: str, **args: Any) -> None:

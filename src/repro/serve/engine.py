@@ -16,8 +16,9 @@ serving directly):
   (eos / max tokens), streaming emission via ``on_token``, and a stats
   surface (queue depth, slot occupancy, prefill/decode split, tokens/s)
   built on :mod:`repro.obs` — counters/gauges/latency histograms in a
-  metrics registry, prefill/decode spans on the active tracer, and an
-  optional live-workload recorder (see :class:`ContinuousEngine`).
+  metrics registry, spans at each stage of a step on the profiler's
+  timeline and the active tracer, and an optional live-workload recorder
+  (see :class:`ContinuousEngine`).
 
   With ``ServeConfig(paged=True)`` the continuous engine swaps the per-slot
   contiguous cache segments for a paged KV store (``repro.serve.pages``):
@@ -236,7 +237,13 @@ class Request:
     """One generation request.  ``prompt`` is an unbatched (S,) token vector;
     ``extra`` holds unbatched per-request extra inputs (``enc_embeds`` for
     enc-dec archs, ``embeds`` for VLM embedding prompts) — the engine adds
-    the batch axis."""
+    the batch axis.
+
+    The engine stamps each stage on ``time.perf_counter``: ``submitted_at``
+    (``submit``), ``slot_at`` (it left the FIFO for a decode slot),
+    ``prefill_at`` (its first prefill or prefill-chunk dispatch),
+    ``first_token_at`` (its first token reached the host) and
+    ``finished_at``."""
 
     uid: int
     prompt: np.ndarray
@@ -246,7 +253,9 @@ class Request:
     # -- filled by the engine ------------------------------------------------
     tokens: list[int] = dataclasses.field(default_factory=list)
     submitted_at: float = 0.0
-    admitted_at: float | None = None
+    slot_at: float | None = None
+    prefill_at: float | None = None
+    first_token_at: float | None = None
     finished_at: float | None = None
 
     @property
@@ -258,12 +267,17 @@ class Request:
         return np.asarray(self.tokens, np.int32)
 
 
-#: the engine's cumulative counters; ``stats`` assembles them in this order
+#: the engine's cumulative counters; ``stats`` assembles them in this order.
+#: ``prefill_wait_s`` sums ``prefill_at - submitted_at`` over the
+#: ``prefill_starts`` requests that reached their first prefill; ``host_s``
+#: sums each step's duration less the time it blocked on device results
+#: (its ``.wait`` spans)
 _STAT_KEYS = ("prefill_s", "decode_s", "tokens_out", "prefill_tokens",
               "submitted", "admitted", "completed", "steps", "decode_steps",
               "occupancy_sum", "queue_depth_sum", "prefill_compiles",
               "prefix_hits", "prefix_tokens_saved", "chunk_steps",
-              "schedule_swaps")
+              "schedule_swaps", "prefill_wait_s", "prefill_starts",
+              "host_s")
 
 
 @dataclasses.dataclass
@@ -310,10 +324,15 @@ class ContinuousEngine:
     a :class:`~repro.obs.metrics.MetricsRegistry` (``obs`` — engine-local by
     default so concurrent engines never share counters; pass one to fold a
     serve run into a wider snapshot), alongside TTFT / inter-token-latency /
-    dispatch-time histograms and occupancy / queue-depth gauges.  Prefill
-    and decode dispatches are traced as spans on the active
-    ``repro.obs.trace`` tracer, and an optional :class:`WorkloadRecorder`
-    logs the live (shape, dtype, occupancy) mix for offline tuning.
+    dispatch-time histograms and occupancy / queue-depth gauges.  Each
+    step is a ``serve.step`` span (``repro.obs.trace``: the profiler's
+    timeline, and the active tracer) with one level of children —
+    ``serve.admit``, ``serve.prefill`` / ``serve.prefill_chunk`` /
+    ``serve.decode`` each with a ``.wait`` child around the call that
+    blocks on the device, and ``serve.emit`` — and a finished request is a
+    ``serve.request`` instant on the active tracer.  An optional
+    :class:`WorkloadRecorder` logs the live (shape, dtype, occupancy) mix
+    for offline tuning.
     """
 
     def __init__(self, params, cfg: ModelConfig,
@@ -418,6 +437,7 @@ class ContinuousEngine:
         self._h_prefill = self.obs.histogram("serve.prefill_call_s")
         self._h_decode = self.obs.histogram("serve.decode_step_s")
         self._last_emit: dict[int, float] = {}   # uid -> last token time
+        self._wait_s = 0.0      # this step's seconds blocked on the device
 
     # ------------------------------------------------------- sharded serving
     def _shard_state(self) -> None:
@@ -759,50 +779,37 @@ class ContinuousEngine:
         """Admit + prefill waiting requests into free slots, then run one
         lockstep decode over the occupied batch.  Returns requests that
         finished during this step."""
-        self._maybe_refresh_schedules()
         finished: list[Request] = []
-        if self.paged:
-            self._admit_paged(finished)
-            if self._chunk_tasks:
-                self._chunk_step(finished)
-            self._decode_paged(finished)
-            self._g_page_occ.set(_ratio(self.pages.used_pages,
-                                        self.pages.usable_pages))
-        else:
-            groups: dict[Any, list[tuple[int, Request]]] = {}
-            for slot, req in self.pool.admit():
-                # coalesce same-shape admissions into one batched prefill —
-                # the per-row math is identical to batch-1, at one dispatch
-                # per group
-                groups.setdefault(_shape_key(req), []).append((slot, req))
-            for group in groups.values():
-                self._admit_group(group, finished)
-            if self.pool.occupancy:
-                self._maybe_refresh_schedules()
-                occ = self.pool.occupancy
-                t0 = time.perf_counter()
-                with obs_trace.span("serve.decode", occupancy=occ):
-                    self._key, sub = jax.random.split(self._key)
-                    tok, self.caches = self._decode(
-                        self.params, self.caches, jnp.asarray(self.tokens),
-                        key=sub)
-                    tok = np.asarray(tok)
-                dt = time.perf_counter() - t0
-                self._c["decode_s"].inc(dt)
-                self._c["decode_steps"].inc()
-                self._h_decode.record(dt)
-                if self.recorder is not None:
-                    self.recorder.record("decode", batch=self.capacity,
-                                         dtype=self.cfg.dtype, occupancy=occ,
-                                         queue_depth=self.pool.queue_depth)
-                for slot, req in list(self.pool.held()):
-                    self.tokens[slot] = int(tok[slot])
-                    self._emit(slot, req, int(tok[slot]), finished)
-        self._c["steps"].inc()
-        self._c["occupancy_sum"].inc(self.pool.occupancy)
-        self._c["queue_depth_sum"].inc(self.pool.queue_depth)
-        self._g_occupancy.set(self.pool.occupancy)
-        self._g_queue_depth.set(self.pool.queue_depth)
+        self._wait_s = 0.0
+        with obs_trace.span("serve.step") as st:
+            self._maybe_refresh_schedules()
+            if self.paged:
+                self._admit_paged(finished)
+                if self._chunk_tasks:
+                    self._chunk_step(finished)
+                self._decode_paged(finished)
+                self._g_page_occ.set(_ratio(self.pages.used_pages,
+                                            self.pages.usable_pages))
+            else:
+                groups: dict[Any, list[tuple[int, Request]]] = {}
+                with obs_trace.span("serve.admit"):
+                    for slot, req in self.pool.admit():
+                        req.slot_at = time.perf_counter()
+                        # coalesce same-shape admissions into one batched
+                        # prefill — the per-row math is identical to
+                        # batch-1, at one dispatch per group
+                        groups.setdefault(_shape_key(req), []).append(
+                            (slot, req))
+                for group in groups.values():
+                    self._admit_group(group, finished)
+                if self.pool.occupancy:
+                    self._decode_batch(self.pool.occupancy, finished)
+            self._c["steps"].inc()
+            self._c["occupancy_sum"].inc(self.pool.occupancy)
+            self._c["queue_depth_sum"].inc(self.pool.queue_depth)
+            self._g_occupancy.set(self.pool.occupancy)
+            self._g_queue_depth.set(self.pool.queue_depth)
+        self._c["host_s"].inc(st.dur_s - self._wait_s)
         return finished
 
     def run(self, max_steps: int | None = None) -> dict[int, np.ndarray]:
@@ -819,30 +826,48 @@ class ContinuousEngine:
         return out
 
     # ------------------------------------------------------------ internals
+    def _wait(self, name: str, fetch: Callable[[Any], Any], x: Any) -> Any:
+        """``fetch(x)`` (a call that blocks on a device result) under the
+        span ``name``: time the step spends waiting, which ``host_s``
+        leaves out."""
+        with obs_trace.span(name) as sp:
+            out = fetch(x)
+        self._wait_s += sp.dur_s
+        return out
+
+    def _prefill_started(self, req: Request, now: float) -> None:
+        if req.prefill_at is None:
+            req.prefill_at = now
+            self._c["prefill_wait_s"].inc(now - req.submitted_at)
+            self._c["prefill_starts"].inc()
+
     def _admit_group(self, group: list[tuple[int, Request]],
                      finished: list[Request]) -> None:
         self._maybe_refresh_schedules()
-        t0 = time.perf_counter()
-        slots = np.asarray([s for s, _ in group], np.int32)
-        prompts = np.stack([r.prompt for _, r in group])
-        inputs = {"tokens": jnp.asarray(prompts)}
-        for k in (group[0][1].extra or {}):
-            inputs[k] = jnp.asarray(
-                np.stack([np.asarray(r.extra[k]) for _, r in group]))
-        if self.paged:
-            # the paged jit is keyed on the page-rounded length, so the
-            # compile counter must be too — exact prompt lengths would
-            # overcount
-            ps = self.pages.page_size
-            n_pg = -(-int(prompts.shape[1]) // ps)
-            shape = (len(group), n_pg * ps)
-        else:
-            shape = (len(group), prompts.shape[1])
-        if shape not in self._prefill_shapes_seen:
-            self._prefill_shapes_seen.add(shape)
-            self._c["prefill_compiles"].inc()
-        with obs_trace.span("serve.prefill", batch=len(group),
-                            prompt_len=int(prompts.shape[1])):
+        uids = " ".join(str(r.uid) for _, r in group)
+        now = time.perf_counter()
+        for _, req in group:
+            self._prefill_started(req, now)
+        with obs_trace.span("serve.prefill", uids=uids, batch=len(group),
+                            prompt_len=len(group[0][1].prompt)) as sp:
+            slots = np.asarray([s for s, _ in group], np.int32)
+            prompts = np.stack([r.prompt for _, r in group])
+            inputs = {"tokens": jnp.asarray(prompts)}
+            for k in (group[0][1].extra or {}):
+                inputs[k] = jnp.asarray(
+                    np.stack([np.asarray(r.extra[k]) for _, r in group]))
+            if self.paged:
+                # the paged jit is keyed on the page-rounded length, so the
+                # compile counter must be too — exact prompt lengths would
+                # overcount
+                ps = self.pages.page_size
+                n_pg = -(-int(prompts.shape[1]) // ps)
+                shape = (len(group), n_pg * ps)
+            else:
+                shape = (len(group), prompts.shape[1])
+            if shape not in self._prefill_shapes_seen:
+                self._prefill_shapes_seen.add(shape)
+                self._c["prefill_compiles"].inc()
             if self.paged:
                 # prefill at the prompt length rounded up to a page multiple
                 # — the group cache then splits exactly into pages, and the
@@ -851,20 +876,20 @@ class ContinuousEngine:
                 page_rows = np.asarray(
                     [self._slot_pages[s][:n_pg] for s in slots], np.int32)
                 self._key, sub = jax.random.split(self._key)
-                toks = np.asarray(_pick(logits, self.scfg.temperature, sub))
+                toks = self._wait("serve.prefill.wait", np.asarray,
+                                  _pick(logits, self.scfg.temperature, sub))
                 self.caches = self._insert_pages(
                     self.caches, grp, jnp.asarray(slots),
                     jnp.asarray(page_rows))
             else:
                 logits, grp = self._prefill(self.params, inputs)
                 self._key, sub = jax.random.split(self._key)
-                toks = np.asarray(_pick(logits, self.scfg.temperature, sub))
+                toks = self._wait("serve.prefill.wait", np.asarray,
+                                  _pick(logits, self.scfg.temperature, sub))
                 self.caches = self._insert(self.caches, grp,
                                            jnp.asarray(slots))
-            jax.block_until_ready(logits)
-        dt = time.perf_counter() - t0
-        self._c["prefill_s"].inc(dt)
-        self._h_prefill.record(dt)
+        self._c["prefill_s"].inc(sp.dur_s)
+        self._h_prefill.record(sp.dur_s)
         self._c["prefill_tokens"].inc(int(prompts.size))
         self._c["admitted"].inc(len(group))
         if self.recorder is not None:
@@ -872,17 +897,18 @@ class ContinuousEngine:
                                  batch=len(group), dtype=self.cfg.dtype,
                                  occupancy=self.pool.occupancy,
                                  queue_depth=self.pool.queue_depth)
-        now = time.perf_counter()
-        for (slot, req), tok in zip(group, toks):
-            req.admitted_at = now
-            self._h_ttft.record(now - req.submitted_at)
-            if self.paged:
-                # register BEFORE _emit: a 1-token request releases its slot
-                # (and pages) inside _emit, and the prefix cache must take
-                # its references first
-                self._register_prefix(req, slot)
-            self.tokens[slot] = int(tok)
-            self._emit(slot, req, int(tok), finished)
+        with obs_trace.span("serve.emit", uids=uids):
+            now = time.perf_counter()
+            for (slot, req), tok in zip(group, toks):
+                req.first_token_at = now
+                self._h_ttft.record(now - req.submitted_at)
+                if self.paged:
+                    # register BEFORE _emit: a 1-token request releases its
+                    # slot (and pages) inside _emit, and the prefix cache
+                    # must take its references first
+                    self._register_prefix(req, slot)
+                self.tokens[slot] = int(tok)
+                self._emit(slot, req, int(tok), finished)
 
     # ------------------------------------------------------ paged internals
     def _admissible(self, worst: int) -> bool:
@@ -903,11 +929,14 @@ class ContinuousEngine:
             req = self.pool.peek()
             if req is None:
                 break
-            plan = self._plan_pages(req)
+            with obs_trace.span("serve.admit", uid=req.uid):
+                plan = self._plan_pages(req)
+                if plan is not None:
+                    slot, _ = self.pool.admit_one()
+                    req.slot_at = time.perf_counter()
+                    self._install(slot, req, plan, groups)
             if plan is None:
                 break
-            slot, _ = self.pool.admit_one()
-            self._install(slot, req, plan, groups)
         for group in groups.values():
             self._admit_group(group, finished)
 
@@ -999,17 +1028,24 @@ class ContinuousEngine:
         if shape not in self._prefill_shapes_seen:
             self._prefill_shapes_seen.add(shape)
             self._c["prefill_compiles"].inc()
-        t0 = time.perf_counter()
-        with obs_trace.span("serve.prefill_chunk", slot=slot, chunk=int(cs),
-                            valid=int(n)):
+        self._prefill_started(req, time.perf_counter())
+        final = task.pos + n >= len(req.prompt)
+        with obs_trace.span("serve.prefill_chunk", uid=req.uid, slot=slot,
+                            chunk=int(cs), valid=int(n)) as sp:
             last, self.caches = self._chunk(
                 self.params, self.caches, jnp.asarray(buf),
                 jnp.asarray(self._pt[slot:slot + 1]), jnp.int32(slot),
                 jnp.int32(n), embeds=embeds)
-            jax.block_until_ready(last)
-        dt = time.perf_counter() - t0
-        self._c["prefill_s"].inc(dt)
-        self._h_prefill.record(dt)
+            if final:
+                self._key, sub = jax.random.split(self._key)
+                tok = int(self._wait(
+                    "serve.prefill_chunk.wait", np.asarray,
+                    _pick(last, self.scfg.temperature, sub))[0])
+            else:
+                self._wait("serve.prefill_chunk.wait", jax.block_until_ready,
+                           last)
+        self._c["prefill_s"].inc(sp.dur_s)
+        self._h_prefill.record(sp.dur_s)
         self._c["prefill_tokens"].inc(int(n))
         self._c["chunk_steps"].inc()
         if self.recorder is not None:
@@ -1018,19 +1054,18 @@ class ContinuousEngine:
                                  occupancy=self.pool.occupancy,
                                  queue_depth=self.pool.queue_depth)
         task.pos += n
-        if task.pos < len(req.prompt):
+        if not final:
             return
         self._chunk_tasks.popleft()
         self._prefilling.discard(slot)
-        self._key, sub = jax.random.split(self._key)
-        tok = int(np.asarray(_pick(last, self.scfg.temperature, sub))[0])
-        now = time.perf_counter()
-        req.admitted_at = now
-        self._h_ttft.record(now - req.submitted_at)
-        self._c["admitted"].inc()
-        self._register_prefix(req, slot)
-        self.tokens[slot] = tok
-        self._emit(slot, req, tok, finished)
+        with obs_trace.span("serve.emit", uid=req.uid):
+            now = time.perf_counter()
+            req.first_token_at = now
+            self._h_ttft.record(now - req.submitted_at)
+            self._c["admitted"].inc()
+            self._register_prefix(req, slot)
+            self.tokens[slot] = tok
+            self._emit(slot, req, tok, finished)
 
     def _register_prefix(self, req: Request, slot: int) -> None:
         """Offer a freshly prefilled prompt's full pages to the prefix cache
@@ -1052,30 +1087,34 @@ class ContinuousEngine:
                     if s not in self._prefilling]
         if not decoding:
             return
-        self._maybe_refresh_schedules()
-        occ = len(decoding)
         active = np.zeros(self.capacity, bool)
         active[decoding] = True
-        t0 = time.perf_counter()
-        with obs_trace.span("serve.decode", occupancy=occ):
+        self._decode_batch(len(decoding), finished, self._pt, active)
+
+    def _decode_batch(self, occ: int, finished: list[Request],
+                      *paged: np.ndarray) -> None:
+        """One decode dispatch over the slot batch (``paged``: the page
+        tables and active mask), then each decoding slot's token emitted."""
+        self._maybe_refresh_schedules()
+        with obs_trace.span("serve.decode", occupancy=occ) as sp:
             self._key, sub = jax.random.split(self._key)
             tok, self.caches = self._decode(
                 self.params, self.caches, jnp.asarray(self.tokens),
-                jnp.asarray(self._pt), jnp.asarray(active), key=sub)
-            tok = np.asarray(tok)
-        dt = time.perf_counter() - t0
-        self._c["decode_s"].inc(dt)
+                *map(jnp.asarray, paged), key=sub)
+            tok = self._wait("serve.decode.wait", np.asarray, tok)
+        self._c["decode_s"].inc(sp.dur_s)
         self._c["decode_steps"].inc()
-        self._h_decode.record(dt)
+        self._h_decode.record(sp.dur_s)
         if self.recorder is not None:
             self.recorder.record("decode", batch=self.capacity,
                                  dtype=self.cfg.dtype, occupancy=occ,
                                  queue_depth=self.pool.queue_depth)
-        for slot, req in list(self.pool.held()):
-            if slot in self._prefilling:
-                continue
-            self.tokens[slot] = int(tok[slot])
-            self._emit(slot, req, int(tok[slot]), finished)
+        with obs_trace.span("serve.emit", tokens=occ):
+            for slot, req in list(self.pool.held()):
+                if self.paged and slot in self._prefilling:
+                    continue
+                self.tokens[slot] = int(tok[slot])
+                self._emit(slot, req, int(tok[slot]), finished)
 
     def _emit(self, slot: int, req: Request, tok: int,
               finished: list[Request]) -> None:
@@ -1092,6 +1131,12 @@ class ContinuousEngine:
                 or (req.eos_id is not None and tok == req.eos_id)):
             req.finished_at = time.perf_counter()
             self._last_emit.pop(req.uid, None)
+            obs_trace.instant(
+                "serve.request", uid=req.uid,
+                slot_wait_s=req.slot_at - req.submitted_at,
+                prefill_wait_s=req.prefill_at - req.submitted_at,
+                ttft_s=req.first_token_at - req.submitted_at,
+                tokens=len(req.tokens))
             # eviction is lazy: a freed slot's stale state is confined to its
             # own batch row (per-slot masks/state), and the next admission's
             # insert overwrites the entire row — so completion costs no

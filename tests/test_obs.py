@@ -1,7 +1,8 @@
 """repro.obs telemetry: metrics registry scoping + histogram edges, tracer
-span nesting + Chrome export + validation, engine instrumentation (registry-
-backed stats, zero-run metrics guards, trace spans), and the
-WorkloadRecorder -> TuningSession round trip."""
+span nesting + Chrome export + validation, spans timed once, the compile
+counter and tuner spans, engine instrumentation (registry-backed stats,
+zero-run metrics guards, the step's spans), and the WorkloadRecorder ->
+TuningSession round trip."""
 
 import json
 import math
@@ -216,14 +217,66 @@ class TestTracer:
         assert len(tids) == 2                 # one track per thread
         assert validate_events(events) == []
 
-    def test_streaming_jsonl_sink(self, tmp_path):
-        p = str(tmp_path / "stream.jsonl")
-        tr = Tracer(jsonl_path=p)
-        with tr.span("s"):
-            pass
-        tr.close()
-        assert validate_trace(p) == []
-        assert len(load_trace(p)) == 1
+
+    def test_span_times_once_on_either_sink(self):
+        """A span measures its interval once: with no tracer it records
+        nothing and still reports ``dur_s``; with one, the event's
+        duration is that same measurement."""
+        assert active_tracer() is None
+        with span("off", a=1) as sp:
+            sp["b"] = 2
+        assert sp.dur_s > 0 and sp.args == {"a": 1, "b": 2}
+        tr = Tracer()
+        with tracing(tr):
+            with span("on") as sp:
+                pass
+        ev, = tr.events()
+        assert ev["dur"] == pytest.approx(sp.dur_s * 1e6, abs=0.01)
+
+    def test_nested_compile_events_count_once(self):
+        """JAX reports a trace that traced inner jitted functions after
+        them, each with its own span: the wall time counts once."""
+        from repro.obs.trace import _CompileWatch
+        watch = _CompileWatch()
+        with metrics_scope() as reg, tracing() as tr:
+            for start, end in ((1.0, 2.0), (3.0, 4.0), (0.0, 5.0),
+                               (6.0, 7.0), (7.5, 8.0)):
+                watch("/jax/core/compile/jaxpr_trace_duration", start, end,
+                      fun_name="f")
+            watch("/jax/compilation_cache/cache_hits", 0.0, 9.0)
+        assert watch.seconds == reg.counter("jax.compile_s").value == 6.5
+        assert reg.counter("jax.compiles").value == 5
+        assert [e["args"]["s"] for e in tr.events()] == \
+            [1.0, 1.0, 5.0, 1.0, 0.5]
+
+    def test_tune_counts_compiles_and_traces_evals(self):
+        """A tune call counts JAX's compile events into the active
+        registry, its own compile seconds as ``tune.compile_s``, and each
+        energy evaluation the memo misses as a ``tune.eval`` span inside
+        its round."""
+        from repro.core.cache import ScheduleCache
+        from repro.core.jit import TuneConfig
+        from repro.core.registry import registry
+        from repro.kernels.gemm_fused import ops as gemm_ops
+        rng = np.random.default_rng(0)
+        x = rng.standard_normal((16, 32)).astype(np.float32)
+        w = rng.standard_normal((32, 16)).astype(np.float32)
+        kern = registry.get(gemm_ops.NAME, cache=ScheduleCache())
+        with metrics_scope() as reg, tracing() as tr:
+            res, = kern.tune([x, w], TuneConfig(
+                rounds=1, t_min=0.25, cooling=1.5, step_samples=1,
+                final_samples=2))
+        assert reg.counter("jax.compiles").value > 0
+        assert 0 < reg.counter("tune.compile_s").value \
+            <= reg.counter("jax.compile_s").value
+        events = tr.events()
+        assert validate_events(events) == []
+        evals = [e for e in events if e["name"] == "tune.eval"]
+        rnd, = [e for e in events if e["name"] == "tune.round"]
+        assert len(evals) == res.cache_stats["misses"]
+        assert all(rnd["ts"] <= e["ts"] and e["ts"] + e["dur"]
+                   <= rnd["ts"] + rnd["dur"] + 0.01 for e in evals)
+        assert any(e["name"] == "jax.compile" for e in events)
 
 
 # ====================================================== engine integration
@@ -288,6 +341,35 @@ class TestEngineObservability:
         assert s["prefill_compiles"] == compiles
         assert s["tokens_out"] == 0 and s["steps"] == 0
         assert eng.obs.histogram("serve.ttft_s").count == 0
+
+    def test_contiguous_step_children(self, params):
+        """The contiguous-cache path has the paged path's step tree, less
+        the chunks: admit, a prefill and a decode each with a ``.wait``,
+        and emission."""
+        tr = Tracer()
+        with tracing(tr):
+            eng = ContinuousEngine(params, CFG,
+                                   ServeConfig(max_len=16, capacity=2))
+            eng.submit(np.zeros(4, np.int32), 3)
+            eng.run(max_steps=100)
+        spans = sorted(((e["ts"], e["ts"] + e["dur"], e["name"])
+                        for e in tr.events() if e["ph"] == "X"),
+                       key=lambda x: (x[0], -x[1]))
+        parent, stack = {}, []
+        for s, e, n in spans:
+            while stack and stack[-1][1] <= s:
+                stack.pop()
+            parent.setdefault(n, set()).add(stack[-1][2] if stack else None)
+            stack.append((s, e, n))
+        assert parent == {
+            "serve.step": {None}, "serve.admit": {"serve.step"},
+            "serve.prefill": {"serve.step"},
+            "serve.prefill.wait": {"serve.prefill"},
+            "serve.decode": {"serve.step"},
+            "serve.decode.wait": {"serve.decode"},
+            "serve.emit": {"serve.step"}}
+        assert eng.stats["prefill_starts"] == 1
+        assert 0 < eng.stats["host_s"]
 
     def test_serve_run_emits_valid_spans(self, params, tmp_path):
         tr = Tracer()

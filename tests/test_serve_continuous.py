@@ -138,7 +138,7 @@ class TestEngineSurface:
         out = eng.run(max_steps=10_000)
         for h in hs:
             assert streamed[h.uid] == list(out[h.uid])   # stream == final
-            assert h.done and h.admitted_at is not None
+            assert h.done and h.first_token_at is not None
         s = eng.stats
         assert s["completed"] == s["submitted"] == len(reqs)
         assert s["tokens_out"] == sum(len(o) for o in out.values())

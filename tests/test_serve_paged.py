@@ -1,8 +1,9 @@
 """Paged-KV serving: differential correctness vs the static reference
 engine (greedy token identity across arrival orderings, with prefix sharing
 and chunked prefill on), page accounting (no leaks, reservation-at-admission),
-the relaxed page-capacity admission bound, and the compile-count guarantee of
-fixed chunk shapes."""
+the relaxed page-capacity admission bound, the compile-count guarantee of
+fixed chunk shapes, and the step's spans on the profiler's timeline and the
+tracer with the request timestamps and counters read from them."""
 
 import numpy as np
 import pytest
@@ -293,3 +294,110 @@ class TestGating:
         with pytest.raises(ValueError, match="attention"):
             M.decode_step(sp, eng.caches, np.zeros(8, np.int32), ssm,
                           pt=np.zeros((8, 2), np.int32))
+
+
+# ================================================================ tracing
+def _parents(spans):
+    """name -> set of names of the innermost span holding it, for
+    ``(start, end, name)`` spans of one thread."""
+    out: dict[str, set[str]] = {}
+    for s, e, n in spans:
+        holders = [h for h in spans if h[0] <= s and e <= h[1]
+                   and (h[1] - h[0]) > (e - s)]
+        inner = min(holders, key=lambda h: h[1] - h[0], default=None)
+        out.setdefault(n, set()).add(None if inner is None else inner[2])
+    return out
+
+
+#: one level of children under serve.step, one .wait under each dispatch
+STEP_TREE = {"serve.step": {None}, "serve.admit": {"serve.step"},
+             "serve.prefill": {"serve.step"},
+             "serve.prefill.wait": {"serve.prefill"},
+             "serve.prefill_chunk": {"serve.step"},
+             "serve.prefill_chunk.wait": {"serve.prefill_chunk"},
+             "serve.decode": {"serve.step"},
+             "serve.decode.wait": {"serve.decode"},
+             "serve.emit": {"serve.step"}}
+
+
+@pytest.fixture(scope="module")
+def traced(params, tmp_path_factory):
+    """A paged engine with chunked prefill and the prefix cache, run under
+    a profiler session and an in-memory tracer at once."""
+    import glob
+    from jax.profiler import ProfileData
+    from repro.obs.trace import Tracer, tracing
+    rng = np.random.default_rng(3)
+    long = rng.integers(1, CFG.vocab, 20).astype(np.int32)
+    reqs = [(long, 4), (rng.integers(1, CFG.vocab, 6).astype(np.int32), 3),
+            (rng.integers(1, CFG.vocab, 7).astype(np.int32), 5),
+            (np.concatenate([long[:17], [5, 6]]).astype(np.int32), 3)]
+    eng = ContinuousEngine(params, CFG, _paged_cfg())
+    tr = Tracer()
+    out = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(out)
+    try:
+        with tracing(tr):
+            handles = [eng.submit(p, b) for p, b in reqs[:3]]
+            eng.step()
+            handles.append(eng.submit(*reqs[3]))
+            eng.run(max_steps=500)
+    finally:
+        jax.profiler.stop_trace()
+    xplane, = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
+    xspans = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+              for plane in ProfileData.from_file(xplane).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("serve.")]
+    return eng, handles, tr.events(), xspans
+
+
+class TestTracing:
+    """Spans on the profiler's timeline and the tracer, and the request
+    timestamps and counters behind the scheduler's per-layer metrics."""
+
+    def test_profiler_timeline_holds_the_step_tree(self, traced):
+        _, _, _, xspans = traced
+        assert _parents(xspans) == STEP_TREE
+
+    def test_tracer_holds_the_same_tree_and_validates(self, traced):
+        from repro.obs.trace import validate_events
+        _, _, events, _ = traced
+        assert validate_events(events) == []
+        spans = [(e["ts"], e["ts"] + e["dur"], e["name"]) for e in events
+                 if e["ph"] == "X"]
+        assert _parents(spans) == STEP_TREE
+
+    def test_prefill_wait_is_the_sum_over_requests(self, traced):
+        eng, handles, _, _ = traced
+        s = eng.stats
+        assert s["prefill_starts"] == len(handles)
+        assert s["prefill_wait_s"] == pytest.approx(
+            sum(h.prefill_at - h.submitted_at for h in handles), rel=1e-9)
+        for h in handles:
+            assert (h.submitted_at <= h.slot_at <= h.prefill_at
+                    <= h.first_token_at <= h.finished_at)
+
+    def test_host_time_is_inside_the_steps(self, traced):
+        eng, _, events, _ = traced
+        steps = [e["dur"] * 1e-6 for e in events
+                 if e["name"] == "serve.step"]
+        waits = [e["dur"] * 1e-6 for e in events
+                 if e["name"].endswith(".wait")]
+        host = eng.stats["host_s"]
+        assert len(steps) == eng.stats["steps"]
+        assert 0 < host <= sum(steps)
+        assert host == pytest.approx(sum(steps) - sum(waits), abs=1e-3)
+
+    def test_finished_requests_are_instants(self, traced):
+        _, handles, events, _ = traced
+        got = {e["args"]["uid"]: e["args"] for e in events
+               if e["name"] == "serve.request"}
+        assert sorted(got) == sorted(h.uid for h in handles)
+        for h in handles:
+            a = got[h.uid]
+            assert a["tokens"] == len(h.tokens)
+            assert a["ttft_s"] == pytest.approx(
+                h.first_token_at - h.submitted_at)
+            assert a["slot_wait_s"] <= a["prefill_wait_s"] <= a["ttft_s"]
