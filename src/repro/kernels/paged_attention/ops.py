@@ -28,7 +28,7 @@ def _divisors(dim: int, prefs: tuple[int, ...]) -> tuple[int, ...]:
     return ch or (1,)
 
 
-def space(*, p, ps, h, d, b, n, dtype="float32") -> SearchSpace:
+def space(*, ps, h, d, b, n, dtype="float32") -> SearchSpace:
     """Copy-tiling knobs: ``rows`` splits the page's ps positions into row
     blocks, ``n_chunks`` splits the head dim — together they set the tile
     count of the movable load/store stream."""
@@ -54,9 +54,12 @@ def program_for(schedule: Schedule, **static):
 
 
 def signature_fn(store, page_table) -> dict:
-    p, ps, h, d = store.shape
+    """The store's page count is left out: no knob or program depends on
+    it, and the serving path gathers from a store that holds every layer's
+    pages, so a schedule tuned on one layer's pool serves it too."""
+    _, ps, h, d = store.shape
     b, n = page_table.shape
-    return {"p": int(p), "ps": int(ps), "h": int(h), "d": int(d),
+    return {"ps": int(ps), "h": int(h), "d": int(d),
             "b": int(b), "n": int(n), "dtype": str(jnp.dtype(store.dtype))}
 
 

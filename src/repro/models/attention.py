@@ -239,23 +239,27 @@ def _paged_decode(cache: dict[str, Any], q, k, v, cfg: ModelConfig, *,
     """Page-table-indirect cache write + read (continuous batching over a
     paged KV store).
 
-    ``cache`` holds the flat page stores ``k``/``v`` (P, ps, Hkv, D), the
-    per-slot lengths ``len`` (B,), and routing keys the model layer injects
-    per step: ``pt`` (B, n_pages) int32 page tables, optional ``active``
+    ``cache`` holds the flat page stores ``k``/``v`` (L*P, ps, Hkv, D) of
+    every layer, this layer's per-slot lengths ``len`` (B,), and routing
+    keys the model layer injects per step: ``pt`` (B, n_pages) int32 page
+    tables, ``base`` scalar (this layer's page ``i`` is store page ``base +
+    i``; its trash page is ``base``), optional ``active``
     (B,) bool (rows mid-chunked-prefill or idle write to the trash page and
     do not advance), optional ``n_valid`` scalar (chunked prefill: how many
     of the s positions are real tokens — padding still writes, but beyond
     ``len + n_valid`` positions are never read because the engine reserves
     worst-case pages per slot and reads are bounded by ``len``).
 
-    Writes scatter each token at (pt[b, pos // ps], pos % ps); reads gather
-    the slot's pages back into a contiguous (B, n*ps, Hkv, D) view (the
-    SIP-tuned ``paged_gather`` kernel under ``cfg.use_pallas``) and reuse
-    the per-slot masked SDPA unchanged.  Sliding-window archs keep the
-    dense ring buffer (the engine gates paging to window=None families).
+    Writes scatter each token at (base + pt[b, pos // ps], pos % ps);
+    reads gather the slot's pages back into a contiguous (B, n*ps, Hkv, D)
+    view (the SIP-tuned ``paged_gather`` kernel under ``cfg.use_pallas``)
+    and reuse the per-slot masked SDPA unchanged.  Sliding-window archs
+    keep the dense ring buffer (the engine gates paging to window=None
+    families).
     """
     store_k, store_v, idx = cache["k"], cache["v"], cache["len"]
     pt = cache["pt"]
+    base = cache["base"]
     active = cache.get("active")
     n_valid = cache.get("n_valid")
     b, s = q.shape[0], q.shape[1]
@@ -273,10 +277,12 @@ def _paged_decode(cache: dict[str, Any], q, k, v, cfg: ModelConfig, *,
     page_ids = jnp.where(page_slot < n_pages, page_ids, 0)
     if active is not None:
         page_ids = jnp.where(active[:, None], page_ids, 0)  # trash page
+    page_ids = page_ids + base
     offs = pos % ps
     ck = store_k.at[page_ids, offs].set(k.astype(store_k.dtype))
     cv = store_v.at[page_ids, offs].set(v.astype(store_v.dtype))
 
+    pt = pt + base
     gk = _gather_pages(ck, pt, cfg)                        # (B, n*ps, Hkv, D)
     gv = _gather_pages(cv, pt, cfg)
     o = _sdpa(q, gk, gv, causal=causal, window=None,

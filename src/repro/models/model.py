@@ -407,22 +407,51 @@ def decode_tokens(p: Params, caches, tokens: jnp.ndarray, cfg: ModelConfig, *,
         else p["embed"].astype(dt)[tokens]              # (B, S, d)
     x = shard(x, "batch", "seq", None)
 
-    def body(h, xs):
-        lp, cache = xs
-        if pt is not None:
-            cache = dict(cache, pt=pt)
-            if active is not None:
-                cache["active"] = active
-            if n_valid is not None:
-                cache["n_valid"] = n_valid
-        h, _, cache = blocks.decoder_block(
-            lp, h, cfg, causal=True, pos_offset=cache["len"], cache=cache)
-        return h, cache
+    if pt is not None:
+        x, caches = _paged_layers(p, x, caches, cfg, pt=pt, active=active,
+                                  n_valid=n_valid)
+    else:
+        def body(h, xs):
+            lp, cache = xs
+            h, _, cache = blocks.decoder_block(
+                lp, h, cfg, causal=True, pos_offset=cache["len"], cache=cache)
+            return h, cache
 
-    x, caches = _scan(body, x, (p["blocks"], caches), cfg)
+        x, caches = _scan(body, x, (p["blocks"], caches), cfg)
     x = nn.rmsnorm_apply(p["ln_f"], x)
     logits = x @ p["lm_head"].astype(x.dtype)
     return logits, caches
+
+
+def _paged_layers(p, x, caches, cfg: ModelConfig, **route):
+    """The layer loop over the paged store (L, P, ps, H, D).
+
+    The ``k``/``v`` stores ride the loop's carry as one flat (L*P, ps, H, D)
+    store each, layer ``l``'s page ``i`` at ``l*P + i`` (its trash page at
+    ``l*P``), and each layer's token writes and gather address that store
+    in place through the ``base`` routing key.  Scanned as xs/ys instead,
+    every step would slice each layer's store out for the gather, restack
+    the written slices into a new store and copy it back to the donated
+    buffer.  Only the per-layer ``len`` (L, B) is scanned over.
+    """
+    n_layers, n_pages = caches["k"].shape[:2]
+    stores = {n: caches[n].reshape((n_layers * n_pages,) + caches[n].shape[2:])
+              for n in ("k", "v")}
+    route = {k: v for k, v in route.items() if v is not None}
+
+    def body(carry, xs):
+        h, stores = carry
+        lp, lens, layer = xs
+        cache = dict(stores, len=lens, base=layer * n_pages, **route)
+        h, _, cache = blocks.decoder_block(
+            lp, h, cfg, causal=True, pos_offset=lens, cache=cache)
+        return (h, {n: cache[n] for n in stores}), cache["len"]
+
+    layers = jnp.arange(n_layers, dtype=jnp.int32)
+    (x, stores), lens = _scan(body, (x, stores),
+                              (p["blocks"], caches["len"], layers), cfg)
+    return x, dict(caches, len=lens,
+                   **{n: s.reshape(caches[n].shape) for n, s in stores.items()})
 
 
 def _hybrid_decode(p, x, caches, cfg: ModelConfig):

@@ -210,6 +210,8 @@ def serve_sharded():
                        f"_{'rev' if reverse else 'fwd'}")
                 out[key] = got == ref
         out[f"mesh{n}_gspmd"] = run(mesh=mesh, tp_mode="gspmd") == ref
+        out[f"mesh{n}_gspmd_paged"] = run(mesh=mesh, paged=True,
+                                          tp_mode="gspmd") == ref
         comp = run(mesh=mesh, compressed_collectives=True)
         out[f"mesh{n}_compressed_served"] = sorted(
             len(v) for v in comp.values()) == sorted(b for _, b in reqs)

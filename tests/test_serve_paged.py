@@ -5,10 +5,15 @@ the relaxed page-capacity admission bound, the compile-count guarantee of
 fixed chunk shapes, and the step's spans on the profiler's timeline and the
 tracer with the request timestamps and counters read from them."""
 
+import dataclasses
+import functools
+import re
+
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.models import model as M
 from repro.models import modules as nn
@@ -294,6 +299,196 @@ class TestGating:
         with pytest.raises(ValueError, match="attention"):
             M.decode_step(sp, eng.caches, np.zeros(8, np.int32), ssm,
                           pt=np.zeros((8, 2), np.int32))
+
+
+# ============================================= the layer loop's flat store
+# Three layers, three slots of four 8-token pages over a 13-page pool (page 0
+# is each layer's trash page): the paged decode and chunk steps against the
+# dense slot-cache decode on the same cache contents.
+CFG3 = ModelConfig(name="t3", family="dense", n_layers=3, d_model=64,
+                   n_heads=4, n_kv_heads=2, d_ff=128, vocab=128,
+                   dtype="float32").validate()
+CAP, PS, N_PG, POOL = 3, 8, 4, 13
+LENS = np.array([5, 0, 17], np.int32)
+
+
+@pytest.fixture(scope="module")
+def params3():
+    return nn.unwrap(M.init_lm(jax.random.PRNGKey(1), CFG3))
+
+
+def _store_and_dense(params3, cfg):
+    """A paged cache filled with noise, its page tables, and the dense
+    (L, B, N_PG*PS, H, D) slot cache that holds the same positions."""
+    ex = {"tokens": np.zeros((1, 8), np.int32)}
+    caches, axes = M.alloc_paged_caches(params3, cfg, CAP, N_PG * PS, PS,
+                                        POOL, ex)
+    rng = np.random.default_rng(3)
+    for n in ("k", "v"):
+        caches[n] = jnp.asarray(
+            rng.standard_normal(caches[n].shape).astype(np.float32))
+    caches["len"] = jnp.asarray(np.tile(LENS, (cfg.n_layers, 1)))
+    pt = rng.permutation(np.arange(1, POOL))[:CAP * N_PG].reshape(CAP, N_PG)
+    pt = pt.astype(np.int32)
+    l, _, ps, h, d = caches["k"].shape
+    dense = {n: np.asarray(caches[n])[:, pt].reshape(l, CAP, N_PG * ps, h, d)
+             for n in ("k", "v")}
+    return caches, axes, pt, dense
+
+
+def _pages_of(dense, pt, rows):
+    """Expected page contents (L, POOL, ps, H, D) after writing the dense
+    caches' rows ``rows`` back through their page tables; every other page
+    keeps ``dense["init"]``."""
+    out = np.array(dense["init"])
+    l, pool, ps, h, d = out.shape
+    for b in rows:
+        out[:, pt[b]] = dense["new"][:, b].reshape(l, N_PG, ps, h, d)
+    return out
+
+
+def _assert_layers_equal(got, want):
+    """Layer by layer, every page but that layer's trash page (index 0 of
+    the layer: page ``l*POOL`` of the flat store)."""
+    for layer in range(got.shape[0]):
+        np.testing.assert_array_equal(got[layer, 1:], want[layer, 1:],
+                                      err_msg=f"layer {layer}")
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("scan_layers", [True, False])
+class TestFlatStoreLayerLoop:
+    """The paged layer loop carries the page store flat and addresses each
+    layer's pages at ``layer * P + page``: bit-identical to the dense
+    slot-cache decode, and no layer's writes land in another layer's pages."""
+
+    def test_decode_step_matches_dense(self, params3, scan_layers,
+                                       use_pallas):
+        cfg = dataclasses.replace(CFG3, scan_layers=scan_layers,
+                                  use_pallas=use_pallas)
+        caches, _, pt, dense = _store_and_dense(params3, cfg)
+        tok = jnp.asarray([3, 4, 5], jnp.int32)
+        active = np.array([True, False, True])
+        init = {n: np.asarray(caches[n]) for n in ("k", "v")}
+        lens = np.asarray(caches["len"])
+
+        d_logits, d_new = jax.jit(functools.partial(M.decode_step, cfg=cfg))(
+            params3, {"k": jnp.asarray(dense["k"]),
+                      "v": jnp.asarray(dense["v"]),
+                      "len": jnp.asarray(lens)}, tok)
+        logits, new = jax.jit(functools.partial(M.decode_step, cfg=cfg),
+                              donate_argnums=(1,))(
+            params3, caches, tok, pt=jnp.asarray(pt),
+            active=jnp.asarray(active))
+
+        rows = np.flatnonzero(active)
+        np.testing.assert_array_equal(np.asarray(logits)[rows],
+                                      np.asarray(d_logits)[rows])
+        for n in ("k", "v"):
+            want = _pages_of({"init": init[n], "new": np.asarray(d_new[n])},
+                             pt, rows)
+            got = np.asarray(new[n])
+            _assert_layers_equal(got, want)
+            # the idle row (len 0) wrote its token into offset 0 of every
+            # layer's own trash page
+            for layer in range(cfg.n_layers):
+                assert not np.array_equal(got[layer, 0, 0],
+                                          init[n][layer, 0, 0]), layer
+        np.testing.assert_array_equal(np.asarray(new["len"]),
+                                      lens + active)
+
+    def test_padded_final_chunk_matches_dense(self, params3, scan_layers,
+                                              use_pallas):
+        cfg = dataclasses.replace(CFG3, scan_layers=scan_layers,
+                                  use_pallas=use_pallas)
+        caches, axes, pt, dense = _store_and_dense(params3, cfg)
+        slot, n_valid = 0, 5               # 8-token chunk, 5 real tokens
+        chunk = np.random.default_rng(4).integers(1, CFG3.vocab, (1, PS))
+        chunk[:, n_valid:] = 0
+        chunk = jnp.asarray(chunk.astype(np.int32))
+        init = {n: np.asarray(caches[n]) for n in ("k", "v")}
+        lens = np.asarray(caches["len"])
+
+        one = {n: jnp.asarray(dense[n][:, slot:slot + 1]) for n in ("k", "v")}
+        one["len"] = jnp.asarray(lens[:, slot])     # (L,) scalar per layer
+        d_logits, d_new = jax.jit(functools.partial(M.decode_tokens, cfg=cfg))(
+            params3, one, chunk)
+        last, new = jax.jit(functools.partial(M.prefill_chunk, cfg=cfg,
+                                              axes=axes),
+                            donate_argnums=(1,))(
+            params3, caches, chunk, jnp.asarray(pt[slot:slot + 1]), slot,
+            n_valid)
+
+        np.testing.assert_array_equal(np.asarray(last),
+                                      np.asarray(d_logits)[:, n_valid - 1])
+        for n in ("k", "v"):
+            new_rows = np.array(dense[n])
+            new_rows[:, slot] = np.asarray(d_new[n])[:, 0]
+            want = _pages_of({"init": init[n], "new": new_rows}, pt, [slot])
+            _assert_layers_equal(np.asarray(new[n]), want)
+        want_len = lens.copy()
+        want_len[:, slot] += n_valid
+        np.testing.assert_array_equal(np.asarray(new["len"]), want_len)
+
+
+def test_served_gather_keys_the_adapters_schedule(params3, monkeypatch):
+    """The autotune adapter tunes ``paged_gather`` on one layer's pool; the
+    layer loop gathers from the flat store of every layer.  Both resolve
+    the same schedule key."""
+    from repro.autotune.adapters import serve_targets
+    from repro.kernels.paged_attention import ops as pg_ops
+    from repro.obs.recorder import WorkloadKey
+
+    cfg = dataclasses.replace(CFG3, use_pallas=True)
+    kern, real, served = pg_ops.kernel(), pg_ops.paged_gather, []
+
+    def gather(store, table):
+        served.append(kern.static_of(store, table))
+        return real(store, table)
+
+    monkeypatch.setattr(pg_ops, "paged_gather", gather)
+    caches, _, pt, _ = _store_and_dense(params3, cfg)
+    M.decode_step(params3, caches, jnp.zeros((CAP,), jnp.int32), cfg,
+                  pt=jnp.asarray(pt), active=jnp.ones((CAP,), bool))
+    scfg = ServeConfig(max_len=N_PG * PS, capacity=CAP, paged=True,
+                       page_size=PS, num_pages=POOL)
+    target = serve_targets(cfg, scfg)(WorkloadKey("decode", 0, CAP,
+                                                  "float32"))
+    tuned = kern.static_of(*target.workload.make_args(
+        np.random.default_rng(0)))
+    assert served and all(s == tuned for s in served), (served, tuned)
+
+
+#: an HLO instruction's result dims and opcode: `%x = f32[3,13]{1,0} copy(`
+_HLO_OP = re.compile(r"= \w+\[([\d,]*)\]\{[\d,]*\} ([\w-]+)\(")
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_paged_programs_do_not_restack_the_store(params3, program,
+                                                 scan_layers):
+    """The compiled paged decode and chunk programs (cache donated, as the
+    engine dispatches them) hold no op that rebuilds or copies a whole page
+    store: no dynamic-update-slice, concatenate or copy whose result has
+    the store's (L, P, ps, H, D) shape or its flat (L*P, ps, H, D) form."""
+    cfg = dataclasses.replace(CFG3, scan_layers=scan_layers)
+    caches, axes, pt, _ = _store_and_dense(params3, cfg)
+    if program == "decode":
+        def fn(p, c, t, pt_, a):
+            return M.decode_step(p, c, t, cfg, pt=pt_, active=a)
+        args = (params3, caches, jnp.zeros((CAP,), jnp.int32),
+                jnp.asarray(pt), jnp.ones((CAP,), bool))
+    else:
+        fn = functools.partial(M.prefill_chunk, cfg=cfg, axes=axes)
+        args = (params3, caches, jnp.zeros((1, PS), jnp.int32),
+                jnp.asarray(pt[:1]), 0, 5)
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+    l, pool, ps, h, d = caches["k"].shape
+    stores = {f"{l},{pool},{ps},{h},{d}", f"{l * pool},{ps},{h},{d}"}
+    bad = [m.group(0) for m in _HLO_OP.finditer(hlo)
+           if m.group(1) in stores
+           and m.group(2) in ("dynamic-update-slice", "concatenate", "copy")]
+    assert not bad, "\n".join(bad)
 
 
 # ================================================================ tracing

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +82,7 @@ def test_flash_attention_default_schedule_compiles(one_chip, sq, d):
 def test_paged_gather_default_schedule_compiles(one_chip, d):
     """The paged-KV read: a (P, 16, 8, d) bf16 page store through (B, n)
     page tables."""
-    static = dict(p=273, ps=16, h=8, d=d, b=8, n=34, dtype="bfloat16")
+    static = dict(ps=16, h=8, d=d, b=8, n=34, dtype="bfloat16")
     knobs = pg_ops.space(**static).default_knobs()
     store = jax.ShapeDtypeStruct((273, 16, 8, d), jnp.bfloat16,
                                  sharding=one_chip)
@@ -90,19 +91,27 @@ def test_paged_gather_default_schedule_compiles(one_chip, d):
                                **knobs), store, pt)
 
 
-def test_qwen3_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
-    """The engine's paged decode step at qwen3-1.7b's full width and depth
-    (f32 parameters, bf16 compute, 8 slots of 544 tokens) compiles with its
-    kernels and fits one chip's memory."""
+#: the paged cache of the qwen3-1.7b step compiles: slots, max_len, page
+CAP, MAX_LEN, PS = 8, 544, 16
+N_PG = -(-MAX_LEN // PS)
+
+#: an HLO instruction's result dims and opcode
+_HLO_OP = re.compile(r"= \w+\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(")
+
+
+def _qwen3_paged(one_chip, monkeypatch):
+    """qwen3-1.7b at full width and depth with compiled kernels (f32
+    parameters, bf16 compute) over the paged cache above: (cfg, parameter
+    shapes, cache shapes, cache axes, an argument maker) on the described
+    chip."""
     monkeypatch.setattr(pg_kernel, "interpret_mode", lambda: False)
     cfg = dataclasses.replace(configs.get("qwen3-1.7b"), use_pallas=True)
-    cap, max_len, ps = 8, 544, 16
-    n_pg = -(-max_len // ps)
     shapes = jax.eval_shape(lambda k: nn.unwrap(M.init_lm(k, cfg)),
                             jax.random.PRNGKey(0))
     ex = {"tokens": np.zeros((1, 8), np.int32)}
     caches = jax.eval_shape(lambda p: M.alloc_paged_caches(
-        p, cfg, cap, max_len, ps, cap * n_pg + 1, ex)[0], shapes)
+        p, cfg, CAP, MAX_LEN, PS, CAP * N_PG + 1, ex)[0], shapes)
+    axes = M.paged_cache_axes(shapes, cfg, MAX_LEN, PS, ex)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -111,10 +120,43 @@ def test_qwen3_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    return cfg, on_chip(shapes), on_chip(caches), axes, arg
+
+
+def test_qwen3_paged_decode_step_fits_one_chip(one_chip, monkeypatch):
+    """The engine's paged decode step at qwen3-1.7b's full width and depth
+    (f32 parameters, bf16 compute, 8 slots of 544 tokens) compiles with its
+    kernels and fits one chip's memory."""
+    cfg, shapes, caches, _, arg = _qwen3_paged(one_chip, monkeypatch)
     decode = functools.partial(E._decode_sample_paged, cfg=cfg,
                                temperature=0.0)
-    compiled = _compile(decode, on_chip(shapes), on_chip(caches),
-                        arg((cap,)), arg((cap, n_pg)), arg((cap,), jnp.bool_),
+    compiled = _compile(decode, shapes, caches, arg((CAP,)),
+                        arg((CAP, N_PG)), arg((CAP,), jnp.bool_),
                         key=arg((2,), jnp.uint32))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_qwen3_paged_steps_write_the_store_in_place(one_chip, monkeypatch,
+                                                    program):
+    """On the chip's compiler too, the paged decode and chunk steps, their
+    cache donated as the engine dispatches them, write the page store where
+    it lies: no op rebuilds or copies a whole store."""
+    cfg, shapes, caches, axes, arg = _qwen3_paged(one_chip, monkeypatch)
+    if program == "decode":
+        fn = functools.partial(E._decode_sample_paged, cfg=cfg,
+                               temperature=0.0)
+        args = (arg((CAP,)), arg((CAP, N_PG)), arg((CAP,), jnp.bool_))
+        kw = {"key": arg((2,), jnp.uint32)}
+    else:
+        fn = functools.partial(M.prefill_chunk, cfg=cfg, axes=axes)
+        args, kw = (arg((1, 256)), arg((1, N_PG)), arg(()), arg(())), {}
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        shapes, caches, *args, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    l, pool = caches["k"].shape[:2]
+    bad = [m.group(0) for m in _HLO_OP.finditer(compiled.as_text())
+           if m.group(1).startswith((f"{l},{pool},", f"{l * pool},"))
+           and m.group(2) in ("dynamic-update-slice", "concatenate", "copy")]
+    assert not bad, "\n".join(bad)
