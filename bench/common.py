@@ -4,6 +4,7 @@ annotations on the profiler's clock, and the run context."""
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import pathlib
 import sys
@@ -89,6 +90,25 @@ class Run:
     t_start: float                  # perf_counter at process start
     trace_dir: pathlib.Path
     control: bool = False           # also read the float8 control
+
+
+def load_file(root: pathlib.Path, rel: str):
+    """The Python file at ``rel`` in the checkout, imported by its path
+    once per process under its dotted path (``bench/metrics/x.py`` is
+    ``bench.metrics.x``), so that an import by name finds the same module.
+    Metric readers and configurations' model modules are found so: a new
+    one is a new file, which no existing file names."""
+    name = rel.removesuffix(".py").replace("/", ".")
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, root / rel)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
 def eprint(*a) -> None:

@@ -1,20 +1,17 @@
-"""The paged-KV gather's share of its roofline (%): the bytes the gathers of
-the traced steps read and write (``bench/flops.py``), at HBM bandwidth, over
-the device time of the compiled kernels in the decode and chunk programs.
-The gather is bound by bytes: it does no arithmetic."""
+"""The paged-KV gather's share of its roofline (%): the cache bytes that
+the attention of the traced steps must read, each decoded or prefilled
+row's live pages, K and V, every layer, once (the model module's
+``kv_read_bytes``), at HBM bandwidth, over the device time of the compiled
+kernels in the decode and chunk programs.  Pages a row does not hold, and
+the gathered view's write, count for nothing, so the count is the same
+whatever implements the gather.  The gather is bound by bytes: it does no
+arithmetic."""
 
-from bench import flops
 from bench.metrics import _serve
 
 
 def read(w):
     t = sum(_serve.kernel_seconds(w, k) for k in ("decode", "chunk"))
-    if not t:
+    if not t or not w.traced_kv_bytes:
         return None
-    e, s = w.engine_cfg, w.spec
-    pages = -(-e["max_len"] // e["page_size"])
-    per = lambda rows: 2 * s.layers * flops.gather_bytes(   # k and v
-        rows, pages, e["page_size"], s.kv_heads // w.chips, s.head_dim, 2)
-    b = (w.traced_decode_steps * per(e["capacity"])
-         + w.traced_chunk_steps * per(1))
-    return 100.0 * b / (t * w.peaks.hbm_bytes_per_s)
+    return 100.0 * w.traced_kv_bytes / (t * w.chips * w.peaks.hbm_bytes_per_s)

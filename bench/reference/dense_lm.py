@@ -1,12 +1,21 @@
-"""Plain float32 forward pass of a dense decoder LM (the qwen3 / internlm2
+"""The dense decoder-LM family's model module (the qwen3 / internlm2
 block: RMSNorm, GQA attention with rotary positions and optional per-head
 q/k norm, SwiGLU MLP, an output head tied to the embedding or its own).
 
-It imports nothing of the program.  Its weights come from ``bench.weights``
-and the seed, one layer at a time, so a whole model never has to fit in
-float32 at once; the sequences of a sample run together, padded at the end
-to one fixed length (causal attention keeps padding out of every real
-position), so one compiled block serves every run.
+A configuration file names its model module under ``"model"``; the serving
+harness reaches the family only through it (``bench/serve.py``,
+``model_module``): its sizes (``Spec``), the check that the program serves
+them (``check_program``), its weights in the program's layout
+(``program_params``, ``make``), its plain float32 reference (``logits``)
+and its counts of work (``token_flops``, ``chunk_flops``,
+``kv_read_bytes``).  The weights and the FLOP counts are
+``bench/weights.py``'s and ``bench/flops.py``'s.
+
+The reference imports nothing of the program.  Its weights come from
+``bench.weights`` and the seed, one layer at a time, so a whole model never
+has to fit in float32 at once; the sequences of a sample run together,
+padded at the end to one fixed length (causal attention keeps padding out
+of every real position), so one compiled block serves every run.
 
 ``quant="fp8"`` is the control: the same forward with both operands of
 every projection rounded to float8 e4m3 (per-tensor scale), the precision
@@ -22,6 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.flops import chunk_flops, token_flops
+from bench.weights import Spec, make, program_params
+
+__all__ = ["Spec", "check_program", "program_params", "make", "logits",
+           "token_flops", "chunk_flops", "kv_read_bytes"]
 
 HI = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
@@ -106,6 +120,33 @@ def _head(x, rows, t, *, spec: W.Spec, quant):
     if spec.tied:
         return _mm("brd,vd->brv", h, t["embed"].astype(jnp.float32), quant)
     return _mm("brd,dv->brv", h, t["lm_head"].astype(jnp.float32), quant)
+
+
+KV_ITEMSIZE = 2         # bytes of a cached K or V element: bfloat16
+
+
+def check_program(cfg, spec: W.Spec) -> None:
+    """Raise unless the program's ``ModelConfig`` is a dense SwiGLU decoder
+    of the sizes the configuration file states, attending to every
+    position (no window)."""
+    got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+           cfg.d_ff, cfg.vocab, cfg.qk_norm, cfg.rope_theta, cfg.family,
+           cfg.mlp_type, cfg.window)
+    want = (spec.layers, spec.d_model, spec.heads, spec.kv_heads,
+            spec.head_dim, spec.d_ff, spec.vocab, spec.qk_norm,
+            spec.rope_theta, "dense", "swiglu", None)
+    if got != want:
+        raise ValueError(f"program config {cfg.name!r} gives {got}, the "
+                         f"configuration file states {want}")
+
+
+def kv_read_bytes(spec: W.Spec, ctx: int, page_size: int) -> float:
+    """Cache bytes that attention for one row at context ``ctx`` must read:
+    K and V of each of its ``ceil(ctx / page_size)`` live pages, every
+    layer, once."""
+    pages = -(-ctx // page_size)
+    return (2.0 * spec.layers * pages * page_size * spec.kv_heads
+            * spec.head_dim * KV_ITEMSIZE)
 
 
 def logits(seed: int, spec: W.Spec, seqs, reads, *, pad_to: int,

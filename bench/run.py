@@ -23,7 +23,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -62,12 +61,8 @@ def select(bench: dict, name: str):
 
 
 def reader(root: pathlib.Path, metric: str):
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench.metrics.{metric}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench import common
+    return common.load_file(root, f"bench/metrics/{metric}.py").read
 
 
 def _jax_setup():

@@ -1,14 +1,16 @@
-"""Serving cells: a dense LM served by the program's ``ContinuousEngine``
-under one request mix.
+"""Serving cells: a language model served by the program's
+``ContinuousEngine`` under one request mix.
 
+The harness knows the model's family only through the configuration's
+model module (``model_module``), so a family is added by files alone.
 Set-up makes the weights from the seed on the device, builds the engine
 with the configuration's settings, and runs two requests through it so that
 every program the mix dispatches (prefill chunk, decode step, page-table
 splices, sampling) is compiled before the window opens.  The window drives
 ``submit`` and ``step`` from this one thread; every token is timed where the
 engine hands it to the host (``on_token``).  After the window the served
-tokens of a sample of finished requests are checked against the float32
-reference in ``bench/reference``.
+tokens of a sample of finished requests are checked against the model
+module's float32 reference.
 """
 
 from __future__ import annotations
@@ -19,53 +21,72 @@ import time
 
 import numpy as np
 
-from bench import flops, traffic
+from bench import traffic
 from bench import weights as W
-from bench.common import Check, Outcome, Run, annotate, log, peak_bytes
+from bench.common import (Check, Outcome, Run, annotate, load_file, log,
+                          peak_bytes)
 
 TRACE_AT = 0.4          # the traced steps start at this share of the window
 TRACE_S = 4.0           # and last this long
 
 
-def program_config(config: dict):
+def model_module(root, config: dict):
+    """The configuration's model module, the file its ``"model"`` names: the
+    family's whole contract with the harness.  It provides
+
+    - ``Spec.from_config(config)``: the published sizes, as the file states
+      them; ``spec.vocab`` bounds the token ids the traffic draws;
+    - ``check_program(cfg, spec)``: raises, naming both sides, unless the
+      program's ``ModelConfig`` serves what the file states;
+    - ``program_params(key, spec, dtype)`` and ``make(seed, spec, dtype)``:
+      the weights from the seed, in the program's layout;
+    - ``logits(seed, spec, seqs, reads, *, pad_to, quant=None)``: the plain
+      float32 reference; ``quant="fp8"`` gives its control;
+    - ``token_flops(spec, ctx)`` and ``chunk_flops(spec, start, n, last)``:
+      the FLOPs a token decoded at context ``ctx`` and a prefill chunk
+      need;
+    - ``kv_read_bytes(spec, ctx, page_size)``: the cache bytes that
+      attention for one row at context ``ctx`` must read, summed over
+      layers, in whole pages."""
+    return load_file(root, config["model"])
+
+
+def program_config(model, config: dict):
     """The program's model configuration for a configuration file, held to
-    the sizes the file states."""
+    the sizes the file states by its model module."""
     from repro import configs
     prog = config["program"]
     cfg = dataclasses.replace(configs.get(prog["arch"]), **prog["overrides"])
-    spec = W.Spec.from_config(config)
-    got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-           cfg.d_ff, cfg.vocab, cfg.qk_norm, cfg.rope_theta, cfg.family,
-           cfg.mlp_type, cfg.window)
-    want = (spec.layers, spec.d_model, spec.heads, spec.kv_heads,
-            spec.head_dim, spec.d_ff, spec.vocab, spec.qk_norm,
-            spec.rope_theta, "dense", "swiglu", None)
-    if got != want:
-        raise ValueError(f"program config {prog} gives {got}, the "
-                         f"configuration file states {want}")
+    spec = model.Spec.from_config(config)
+    model.check_program(cfg, spec)
     return cfg, spec
 
 
 class Tokens:
-    """Host arrival time of every token, and the decode work of each step."""
+    """Host arrival time of every token, and the work of each step: the
+    FLOPs of its decoded tokens and prefill chunk, and the cache bytes
+    their attention reads (the model module's counts)."""
 
-    def __init__(self, spec: W.Spec):
-        self.spec = spec
+    def __init__(self, model, spec, page_size: int):
+        self.model, self.spec, self.page_size = model, spec, page_size
         self.times: dict[int, list[float]] = {}
         self.step = 0
         self.decode_flops: dict[int, float] = {}     # step -> FLOPs
         self.chunk_flops: dict[int, float] = {}
-        self.decode_tokens: dict[int, int] = {}
+        self.kv_bytes: dict[int, float] = {}         # step -> bytes
+
+    def _add(self, counts: dict, v) -> None:
+        counts[self.step] = counts.get(self.step, 0) + v
 
     def on_token(self, req, tok) -> None:
         self.times.setdefault(req.uid, []).append(time.perf_counter())
         k = len(req.tokens)
         if k > 1:       # every token after the first comes from a decode
-            f = flops.token_flops(self.spec, len(req.prompt) + k - 1)
-            self.decode_flops[self.step] = \
-                self.decode_flops.get(self.step, 0.0) + f
-            self.decode_tokens[self.step] = \
-                self.decode_tokens.get(self.step, 0) + 1
+            ctx = len(req.prompt) + k - 1
+            self._add(self.decode_flops,
+                      self.model.token_flops(self.spec, ctx))
+            self._add(self.kv_bytes, self.model.kv_read_bytes(
+                self.spec, ctx, self.page_size))
 
     def watch(self, engine, chunk: int) -> bool:
         """Name the engine's prefill-chunk and decode dispatches on the
@@ -81,9 +102,10 @@ class Tokens:
         def traced_chunk(finished):
             t = engine._chunk_tasks[0]
             n = min(chunk, len(t.req.prompt) - t.pos)
-            self.chunk_flops[self.step] = self.chunk_flops.get(
-                self.step, 0.0) + flops.chunk_flops(
-                    self.spec, t.pos, n, t.pos + n >= len(t.req.prompt))
+            self._add(self.chunk_flops, self.model.chunk_flops(
+                self.spec, t.pos, n, t.pos + n >= len(t.req.prompt)))
+            self._add(self.kv_bytes, self.model.kv_read_bytes(
+                self.spec, t.pos + n, self.page_size))
             with annotate("bench.chunk"):
                 return chunk_step(finished)
 
@@ -100,8 +122,7 @@ class Tokens:
 class ServeWindow:
     """What the per-layer readers of a serving cell read."""
 
-    spec: W.Spec
-    engine_cfg: dict
+    spec: object                    # the model module's Spec
     chips: int
     peaks: object
     seconds: float
@@ -111,8 +132,7 @@ class ServeWindow:
     traced: object = None           # trace_reduce.Reduced
     traced_decode_flops: float = 0.0
     traced_chunk_flops: float = 0.0
-    traced_decode_steps: int = 0
-    traced_chunk_steps: int = 0
+    traced_kv_bytes: float = 0.0    # read by the traced steps' attention
     chunks_seen: bool = True
     requests: list = dataclasses.field(default_factory=list)  # (due, first)
     profiler_s: list = dataclasses.field(default_factory=list)  # (from, to)
@@ -135,14 +155,15 @@ def setup(run: Run):
     if run.cell["chips"] != 1:
         raise ValueError("serving cells run on one chip")
     t0 = time.perf_counter()
-    cfg, spec = program_config(run.config)
+    model = model_module(run.root, run.config)
+    cfg, spec = program_config(model, run.config)
     dtype = jnp.dtype(cfg.param_dtype)
     key = jax.random.PRNGKey(0)
-    W.check_layout(jax.eval_shape(lambda k: W.program_params(k, spec, dtype),
-                                  key),
-                   nn.unwrap(M.init_lm_shapes(key, cfg)))
+    W.check_layout(jax.eval_shape(
+        lambda k: model.program_params(k, spec, dtype), key),
+        nn.unwrap(M.init_lm_shapes(key, cfg)))
     with annotate("bench.weights"):
-        params = W.make(run.seed, spec, dtype)
+        params = model.make(run.seed, spec, dtype)
         jax.block_until_ready(params)
     t_weights = time.perf_counter()
     e = run.config["engine"]
@@ -151,7 +172,7 @@ def setup(run: Run):
                        num_pages=e["num_pages"],
                        prefill_chunk=e["prefill_chunk"],
                        prefix_cache=e["prefix_cache"])
-    tokens = Tokens(spec)
+    tokens = Tokens(model, spec, e["page_size"])
     engine = ContinuousEngine(params, cfg, scfg, on_token=tokens.on_token)
     del params
     seen = tokens.watch(engine, e["prefill_chunk"])
@@ -172,7 +193,7 @@ def setup(run: Run):
         engine_s=t_engine - t_weights,
         warmup_s=time.perf_counter() - t_engine,
         compiles=run.clock.events, compile_s=run.clock.seconds)
-    return engine, spec, tokens, seen
+    return engine, model, spec, tokens, seen
 
 
 def _sample(finished, rng, k: int):
@@ -194,21 +215,20 @@ def served_gaps(ref: np.ndarray, served) -> np.ndarray:
     return ref.max(-1) - ref[np.arange(len(served)), served]
 
 
-def check_tokens(run: Run, spec: W.Spec, sample) -> list[Check]:
-    from bench.reference import dense_lm
+def check_tokens(run: Run, model, spec, sample) -> list[Check]:
     seqs = [np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
             for r in sample]
     reads = [np.arange(len(r.prompt) - 1, len(r.prompt) - 1 + len(r.tokens))
              for r in sample]
     pad = run.config["engine"]["max_len"]
-    ref = dense_lm.logits(run.seed, spec, seqs, reads, pad_to=pad)
+    ref = model.logits(run.seed, spec, seqs, reads, pad_to=pad)
     widest = max(float(served_gaps(x, r.tokens).max())
                  for x, r in zip(ref, sample))
     lim = run.config["limits"]["served_logit_gap"]
     checks = [Check("served_logit_gap", widest, lim)]
     if run.control:
         # the token the float8 forward puts first, at each same position
-        low = dense_lm.logits(run.seed, spec, seqs, reads, pad_to=pad,
+        low = model.logits(run.seed, spec, seqs, reads, pad_to=pad,
                               quant="fp8")
         checks.append(Check("control_logit_gap", max(
             float(served_gaps(x, y.argmax(-1)).max())
@@ -350,7 +370,7 @@ def drive(run: Run) -> Outcome:
     import jax
     from bench import peaks as P
     mix, e = run.mix, run.config["engine"]
-    engine, spec, tokens, seen = setup(run)
+    engine, model, spec, tokens, seen = setup(run)
     rng = np.random.default_rng(run.seed)
     with annotate("bench.generator"):
         kind = mix["kind"]
@@ -441,7 +461,7 @@ def drive(run: Run) -> Outcome:
         e2e["itl_p95_ms"] = _percentile_ms(itl, 95)
     steps = range(step0, tokens.step)
     win = ServeWindow(
-        spec=spec, engine_cfg=e, chips=run.cell["chips"],
+        spec=spec, chips=run.cell["chips"],
         peaks=P.for_kind(run.devices[0].device_kind) if run.trace else None,
         seconds=run.seconds, counters=counters,
         decode_flops=sum(tokens.decode_flops.get(s, 0.0) for s in steps),
@@ -453,10 +473,8 @@ def drive(run: Run) -> Outcome:
                                       for s in range(a, b))
         win.traced_chunk_flops = sum(tokens.chunk_flops.get(s, 0.0)
                                      for s in range(a, b))
-        win.traced_decode_steps = sum(1 for s in range(a, b)
-                                      if s in tokens.decode_tokens)
-        win.traced_chunk_steps = sum(1 for s in range(a, b)
-                                     if s in tokens.chunk_flops)
+        win.traced_kv_bytes = sum(tokens.kv_bytes.get(s, 0.0)
+                                  for s in range(a, b))
     # every request that finished in the window, the ramp's too
     finished = [h for h in ramped + [h for _, h in due]
                 if h is not None and h.done and t0 <= h.finished_at < end]
@@ -469,6 +487,6 @@ def drive(run: Run) -> Outcome:
         checks = [Check("finished_requests", 0.0, -1.0)]
     else:
         with annotate("bench.reference"):
-            checks = check_tokens(run, spec, sample)
+            checks = check_tokens(run, model, spec, sample)
     return Outcome(checks=checks, attempted=len(due), failed=0,
                    end_to_end=e2e, window=win, memory_peak_bytes=mem)

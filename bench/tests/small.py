@@ -14,6 +14,7 @@ CONFIG = {
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rope_theta": 1000000, "rms_norm_eps": 1e-06,
     "qk_norm": True, "tie_word_embeddings": True,
+    "model": "bench/reference/dense_lm.py",
     "program": {"arch": "qwen3-1.7b", "overrides": {
         "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
         "head_dim": 16, "d_ff": 128, "vocab": 256, "rope_theta": 1000000.0,
