@@ -1,11 +1,13 @@
 """Schedule-parameterized Pallas paged-KV gather.
 
 ``out[b, i] = store[page_table[b, i]]`` — the cache-read indirection that
-paged serving memory puts on the decode hot path.  One grid step copies one
-page; the page table rides in scalar-prefetch memory (SMEM), so the *input*
-BlockSpec's index map is data-dependent — each step's DMA source block is
-steered by ``pt_ref[b, i]`` at page granularity, the Pallas analogue of the
-page-table walk a paged-attention CUDA kernel does per block.
+paged serving memory puts on the decode hot path.  A page is (ps, H, D)
+(per-head keys or values) or (ps, D) (a latent cache, one vector a
+token).  One grid step copies one page; the page table rides in
+scalar-prefetch memory (SMEM), so the *input* BlockSpec's index map is
+data-dependent — each step's DMA source block is steered by
+``pt_ref[b, i]`` at page granularity, the Pallas analogue of the page-table
+walk a paged-attention CUDA kernel does per block.
 
 The body is emitted from a :class:`~repro.core.ir.Program` whose
 instructions are pure MEM traffic: the page is tiled into (row-block x
@@ -39,11 +41,11 @@ def make_program(*, ps: int, h: int, d: int, rows: int, n_chunks: int,
     instrs: list[Instr] = []
 
     def ld(env, r, c):
-        tile = env["store_ref"][0, pl.ds(r * rb, rb), :, pl.ds(c * cd, cd)]
+        tile = env["store_ref"][0, pl.ds(r * rb, rb), ..., pl.ds(c * cd, cd)]
         return {f"t{r}_{c}": tile}
 
     def st(env, r, c):
-        env["out_ref"][0, 0, pl.ds(r * rb, rb), :, pl.ds(c * cd, cd)] = \
+        env["out_ref"][0, 0, pl.ds(r * rb, rb), ..., pl.ds(c * cd, cd)] = \
             env[f"t{r}_{c}"]
         return {}
 
@@ -64,13 +66,16 @@ def make_program(*, ps: int, h: int, d: int, rows: int, n_chunks: int,
 def paged_gather(store: jax.Array, page_table: jax.Array, *,
                  rows: int, n_chunks: int, order=None,
                  interpret: bool | None = None) -> jax.Array:
-    """store: (P, ps, H, D); page_table: (B, n) int32 -> (B, n, ps, H, D)."""
+    """store: (P, ps, [H,] D); page_table: (B, n) int32 -> (B, n, ps, [H,]
+    D)."""
     if interpret is None:
         interpret = interpret_mode()
-    p, ps, h, d = store.shape
+    p, ps, *mid, d = store.shape
     b, n = page_table.shape
-    program = make_program(ps=ps, h=h, d=d, rows=rows, n_chunks=n_chunks,
-                           dtype=store.dtype, total_pages=b * n)
+    program = make_program(ps=ps, h=mid[0] if mid else 1, d=d, rows=rows,
+                           n_chunks=n_chunks, dtype=store.dtype,
+                           total_pages=b * n)
+    rest = (0,) * (len(mid) + 1)
 
     def kernel(pt_ref, store_ref, out_ref):
         del pt_ref      # consumed by the BlockSpec index maps
@@ -80,14 +85,14 @@ def paged_gather(store: jax.Array, page_table: jax.Array, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, n),
-        in_specs=[pl.BlockSpec((1, ps, h, d),
-                               lambda bi, i, pt_ref: (pt_ref[bi, i], 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, ps, h, d),
-                               lambda bi, i, pt_ref: (bi, i, 0, 0, 0)),
+        in_specs=[pl.BlockSpec((1, ps, *mid, d),
+                               lambda bi, i, pt_ref: (pt_ref[bi, i], 0, *rest))],
+        out_specs=pl.BlockSpec((1, 1, ps, *mid, d),
+                               lambda bi, i, pt_ref: (bi, i, 0, *rest)),
     )
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, n, ps, h, d), store.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n, ps, *mid, d), store.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(page_table.astype(jnp.int32), store)

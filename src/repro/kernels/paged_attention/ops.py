@@ -56,8 +56,10 @@ def program_for(schedule: Schedule, **static):
 def signature_fn(store, page_table) -> dict:
     """The store's page count is left out: no knob or program depends on
     it, and the serving path gathers from a store that holds every layer's
-    pages, so a schedule tuned on one layer's pool serves it too."""
-    _, ps, h, d = store.shape
+    pages, so a schedule tuned on one layer's pool serves it too.  A latent
+    store's (ps, D) page is keyed as (ps, 1, D): the same copy program."""
+    _, ps, *mid, d = store.shape
+    h = mid[0] if mid else 1
     b, n = page_table.shape
     return {"ps": int(ps), "h": int(h), "d": int(d),
             "b": int(b), "n": int(n), "dtype": str(jnp.dtype(store.dtype))}

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 
 
 def paged_gather(store: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """store: (P, ps, H, D); page_table: (B, n) int32 -> (B, n, ps, H, D).
+    """store: (P, ps, [H,] D); page_table: (B, n) int32 -> (B, n, ps, [H,]
+    D).
 
     ``out[b, i] = store[page_table[b, i]]`` — the cache-read indirection of
     paged attention.  Reshaping the result to (B, n*ps, H, D) yields the

@@ -1,4 +1,5 @@
-"""GQA attention with RoPE, qk-norm, sliding window, cross-attn and KV cache.
+"""GQA attention with RoPE, qk-norm, sliding window, cross-attn and KV cache,
+and multi-head latent attention (MLA, DeepSeek-V2) over a latent cache.
 
 Training / prefill use the differentiable jnp path (or the SIP-tuned Pallas
 kernel when ``cfg.use_pallas`` and the path is forward-only); decode operates
@@ -7,6 +8,7 @@ on a preallocated right-padded KV cache with one-token updates.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -18,9 +20,13 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.paged_attention import ops as pg_ops
 from repro.models import modules as nn
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, Yarn
 
 NEG_INF = -1e30
+#: lanes of a TPU vreg: a cached leaf whose last dim is not a multiple of
+#: this gets a transposed default layout on the chip, which the page-store
+#: scatter and the gather kernel can only reach through whole-store copies
+LANES = 128
 
 
 # ------------------------------------------------------------------ params
@@ -54,6 +60,8 @@ def _wo_eff(p, cfg: ModelConfig, dt) -> jnp.ndarray:
 
 
 def init_attention(key, cfg: ModelConfig, cross: bool = False):
+    if cfg.mla:
+        return init_mla(key, cfg)
     hd, h, hkv, d = cfg.hd, phys_heads(cfg), cfg.n_kv_heads, cfg.d_model
     ks = jax.random.split(key, 6)
     wq = jax.random.normal(ks[0], (d, h, hd)) * d ** -0.5
@@ -77,13 +85,17 @@ def init_attention(key, cfg: ModelConfig, cross: bool = False):
 
 
 # ------------------------------------------------------------------- rope
-def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float,
+         yarn: Yarn | None = None) -> jnp.ndarray:
     """x: (B, S, H, D) with D even; pos: (S,) absolute positions shared by the
     batch, or (B, S) per-sequence positions (continuous-batching decode, where
-    every slot sits at its own offset)."""
+    every slot sits at its own offset).  ``yarn`` blends in YaRN's
+    interpolated frequencies and scales cos/sin by its mscale ratio."""
     d = x.shape[-1]
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is not None:
+        freqs = yarn_freqs(freqs, yarn, theta)
     ang = pos.astype(jnp.float32)[..., None] * freqs   # (S, half) | (B, S, half)
     if pos.ndim == 2:
         cos = jnp.cos(ang)[:, :, None, :]
@@ -91,9 +103,34 @@ def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
     else:
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
+    if yarn is not None:
+        mult = yarn.m(yarn.mscale) / yarn.m(yarn.mscale_all_dim)
+        if mult != 1.0:
+            cos, sin = cos * mult, sin * mult
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_freqs(freqs: jnp.ndarray, yarn: Yarn, theta: float) -> jnp.ndarray:
+    """YaRN's inverse frequencies: each rotary pair keeps its frequency
+    (extrapolation) above the ``beta_fast`` correction dim, takes it over
+    ``factor`` (interpolation) below ``beta_slow``'s, and ramps linearly
+    between (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    half = freqs.shape[0]
+    d = 2 * half
+
+    def dim(rotations):         # yarn_find_correction_dim
+        return d * math.log(yarn.original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim(yarn.beta_slow)), d - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo) / (hi - lo),
+                    0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
 def _qkv(p, x: jnp.ndarray, cfg: ModelConfig, pos: jnp.ndarray,
@@ -113,8 +150,10 @@ def _qkv(p, x: jnp.ndarray, cfg: ModelConfig, pos: jnp.ndarray,
 
 def _sdpa(q, k, v, *, causal: bool, window: int | None,
           kv_len: jnp.ndarray | None = None,
-          kv_idx: jnp.ndarray | None = None) -> jnp.ndarray:
-    """q: (B,Sq,H,D); k,v: (B,Skv,Hkv,D) -> (B,Sq,H,D).  jnp path (GQA).
+          kv_idx: jnp.ndarray | None = None,
+          scale: float | None = None) -> jnp.ndarray:
+    """q: (B,Sq,H,D); k: (B,Skv,Hkv,D), v: (B,Skv,Hkv,Dv) -> (B,Sq,H,Dv).
+    jnp path (GQA); scores scaled by ``scale`` (default D ** -0.5).
 
     ``kv_len``: optional scalar — only cache positions < kv_len are valid
     (decode with a preallocated cache) — or a (B,) vector for per-slot decode
@@ -129,7 +168,20 @@ def _sdpa(q, k, v, *, causal: bool, window: int | None,
     group = h // hkv
     qg = q.reshape(b, sq, hkv, group, dh)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (dh ** -0.5)
+                   k.astype(jnp.float32)) * \
+        (dh ** -0.5 if scale is None else scale)
+    mask = _mask(b, sq, skv, causal=causal, window=window, kv_len=kv_len)
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
+    return o.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
+
+
+def _mask(b: int, sq: int, skv: int, *, causal: bool, window: int | None,
+          kv_len: jnp.ndarray | None):
+    """Which keys each query sees: (B, Sq, Skv) where ``kv_len`` is a (B,)
+    vector, else (1, Sq, Skv).  Queries are the last ``sq`` of the valid
+    positions."""
     if kv_len is not None and jnp.ndim(kv_len) > 0:
         # per-slot: mask is (B, sq, skv).  A fully-masked row (an empty slot,
         # kv_len == 0) stays finite — NEG_INF is a large negative, not -inf,
@@ -142,23 +194,25 @@ def _sdpa(q, k, v, *, causal: bool, window: int | None,
             mask &= cols <= rows
         if window is not None:
             mask &= cols > rows - window
-        s = jnp.where(mask[:, None, None], s, NEG_INF)
-    else:
-        rows = jnp.arange(sq)[:, None] + (skv - sq)
-        if kv_len is not None:
-            rows = jnp.arange(sq)[:, None] + (kv_len - sq)
-        cols = jnp.arange(skv)[None, :]
-        mask = jnp.ones((sq, skv), bool)
-        if causal:
-            mask &= cols <= rows
-        if window is not None:
-            mask &= cols > rows - window
-        if kv_len is not None:
-            mask &= cols < kv_len
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return o.reshape(b, sq, h, dh).astype(q.dtype)
+        return mask
+    rows = jnp.arange(sq)[:, None] + (skv - sq)
+    if kv_len is not None:
+        rows = jnp.arange(sq)[:, None] + (kv_len - sq)
+    cols = jnp.arange(skv)[None, :]
+    mask = jnp.ones((sq, skv), bool)
+    if causal:
+        mask &= cols <= rows
+    if window is not None:
+        mask &= cols > rows - window
+    if kv_len is not None:
+        mask &= cols < kv_len
+    return mask[None]
+
+
+def _positions(s: int, pos_offset) -> jnp.ndarray:
+    if jnp.ndim(pos_offset) > 0:
+        return jnp.arange(s)[None, :] + pos_offset[:, None]   # (B, S) per slot
+    return jnp.arange(s) + pos_offset
 
 
 def attention(p, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -172,13 +226,13 @@ def attention(p, x: jnp.ndarray, cfg: ModelConfig, *,
 
     ``pos_offset`` / ``cache['len']`` may be (B,) vectors — per-slot decode
     for the continuous-batching engine, where each batch slot holds a request
-    at its own sequence position.
+    at its own sequence position.  MLA configs go to :func:`mla`.
     """
+    if cfg.mla:
+        return mla(p, x, cfg, causal=causal, pos_offset=pos_offset,
+                   cache=cache, return_cache=return_cache)
     b, s, d = x.shape
-    if jnp.ndim(pos_offset) > 0:
-        pos = jnp.arange(s)[None, :] + pos_offset[:, None]   # (B, S) per slot
-    else:
-        pos = jnp.arange(s) + pos_offset
+    pos = _positions(s, pos_offset)
     q, k, v = _qkv(p, x, cfg, pos)
     q = shard(q, "batch", "seq", "heads", "head_dim")
     k = shard(k, "batch", "seq", "kv_heads", "head_dim")
@@ -187,31 +241,20 @@ def attention(p, x: jnp.ndarray, cfg: ModelConfig, *,
 
     new_cache = None
     if cache is not None and "pt" in cache:     # paged decode / chunk prefill
-        o, new_cache = _paged_decode(cache, q, k, v, cfg, causal=causal,
-                                     kv_idx=kv_idx)
+        views, new_cache = _paged_update(cache, {"k": k, "v": v}, cfg)
+        o = _sdpa(q, views["k"], views["v"], causal=causal, window=None,
+                  kv_len=cache["len"] + s, kv_idx=kv_idx)
     elif cache is not None:                     # decode: append to cache
         idx = cache["len"]
         size = cache["k"].shape[1]
         # SWA ring buffer: slot(p) = p % size once the cache is window-sized
         rolling = cfg.window is not None and size <= cfg.window
         w_idx = idx % size if rolling else idx
-        if jnp.ndim(idx) > 0:
-            # per-slot decode (s == 1): scatter each slot's token at its own
-            # cache position
-            rows_b = jnp.arange(b)
-            ck = cache["k"].at[rows_b, w_idx].set(
-                k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[rows_b, w_idx].set(
-                v[:, 0].astype(cache["v"].dtype))
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, w_idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, w_idx, 0, 0))
-        new_cache = {"k": ck, "v": cv, "len": idx + s}
+        new_cache = dict(_append(cache, {"k": k, "v": v}, w_idx),
+                         len=idx + s)
         # rolling: slot indices are not positions — causal/window masks do not
         # apply; every slot < min(len, size) is in-window by construction.
-        o = _sdpa(q, ck, cv,
+        o = _sdpa(q, new_cache["k"], new_cache["v"],
                   causal=causal and not rolling,
                   window=None if rolling else cfg.window,
                   kv_len=idx + s, kv_idx=kv_idx)
@@ -234,16 +277,36 @@ def attention(p, x: jnp.ndarray, cfg: ModelConfig, *,
     return out
 
 
-def _paged_decode(cache: dict[str, Any], q, k, v, cfg: ModelConfig, *,
-                  causal: bool, kv_idx):
-    """Page-table-indirect cache write + read (continuous batching over a
-    paged KV store).
+def _append(cache: dict[str, Any], new: dict[str, jnp.ndarray], w_idx):
+    """Write each of ``new``'s (B, S, ...) leaves into its contiguous
+    per-slot cache at ``w_idx``: one scalar offset, or a (B,) vector (per-slot
+    decode, S == 1: each slot's token at its own cache position)."""
+    out = {}
+    for n, x in new.items():
+        store = cache[n]
+        if jnp.ndim(w_idx) > 0:
+            out[n] = store.at[jnp.arange(x.shape[0]), w_idx].set(
+                x[:, 0].astype(store.dtype))
+        else:
+            out[n] = jax.lax.dynamic_update_slice(
+                store, x.astype(store.dtype),
+                (0, w_idx) + (0,) * (x.ndim - 2))
+    return out
 
-    ``cache`` holds the flat page stores ``k``/``v`` (L*P, ps, Hkv, D) of
-    every layer, this layer's per-slot lengths ``len`` (B,), and routing
-    keys the model layer injects per step: ``pt`` (B, n_pages) int32 page
-    tables, ``base`` scalar (this layer's page ``i`` is store page ``base +
-    i``; its trash page is ``base``), optional ``active``
+
+def _paged_update(cache: dict[str, Any], new: dict[str, jnp.ndarray],
+                  cfg: ModelConfig):
+    """Page-table-indirect cache write + read (continuous batching over a
+    paged store).  Returns each store's gathered per-slot view and the
+    updated cache.
+
+    ``cache`` holds the flat page stores named in ``new`` (``k``/``v`` of
+    (L*P, ps, H, D), or MLA's ``latent`` of (L*P, ps, D)), each over
+    every layer, this
+    layer's per-slot lengths ``len`` (B,), and routing keys the model layer
+    injects per step: ``pt`` (B, n_pages) int32 page tables, ``base`` scalar
+    (this layer's page ``i`` is store page ``base + i``; its trash page is
+    ``base``), optional ``active``
     (B,) bool (rows mid-chunked-prefill or idle write to the trash page and
     do not advance), optional ``n_valid`` scalar (chunked prefill: how many
     of the s positions are real tokens — padding still writes, but beyond
@@ -251,19 +314,19 @@ def _paged_decode(cache: dict[str, Any], q, k, v, cfg: ModelConfig, *,
     worst-case pages per slot and reads are bounded by ``len``).
 
     Writes scatter each token at (base + pt[b, pos // ps], pos % ps);
-    reads gather the slot's pages back into a contiguous (B, n*ps, Hkv, D)
+    reads gather the slot's pages back into a contiguous (B, n*ps, ...)
     view (the SIP-tuned ``paged_gather`` kernel under ``cfg.use_pallas``)
-    and reuse the per-slot masked SDPA unchanged.  Sliding-window archs
-    keep the dense ring buffer (the engine gates paging to window=None
-    families).
+    for the per-slot masked attention, which reads keys below ``len + s``.
+    Sliding-window archs keep the dense ring buffer (the engine gates
+    paging to window=None families).
     """
-    store_k, store_v, idx = cache["k"], cache["v"], cache["len"]
+    idx = cache["len"]
     pt = cache["pt"]
     base = cache["base"]
     active = cache.get("active")
     n_valid = cache.get("n_valid")
-    b, s = q.shape[0], q.shape[1]
-    ps = store_k.shape[1]
+    b, s = next(iter(new.values())).shape[:2]
+    ps = cache[next(iter(new))].shape[1]
     n_pages = pt.shape[1]
 
     pos = idx[:, None] + jnp.arange(s)[None, :]            # (B, S) absolute
@@ -279,30 +342,154 @@ def _paged_decode(cache: dict[str, Any], q, k, v, cfg: ModelConfig, *,
         page_ids = jnp.where(active[:, None], page_ids, 0)  # trash page
     page_ids = page_ids + base
     offs = pos % ps
-    ck = store_k.at[page_ids, offs].set(k.astype(store_k.dtype))
-    cv = store_v.at[page_ids, offs].set(v.astype(store_v.dtype))
+    stores = {n: cache[n].at[page_ids, offs].set(x.astype(cache[n].dtype))
+              for n, x in new.items()}
 
     pt = pt + base
-    gk = _gather_pages(ck, pt, cfg)                        # (B, n*ps, Hkv, D)
-    gv = _gather_pages(cv, pt, cfg)
-    o = _sdpa(q, gk, gv, causal=causal, window=None,
-              kv_len=idx + s, kv_idx=kv_idx)
+    views = {n: _gather_pages(st, pt, cfg) for n, st in stores.items()}
 
     adv = s if n_valid is None else n_valid
     if active is not None:
         adv = jnp.where(active, adv, 0)
-    return o, {"k": ck, "v": cv, "len": idx + adv}
+    return views, dict(stores, len=idx + adv)
 
 
 def _gather_pages(store, pt, cfg: ModelConfig):
-    """(P, ps, H, D) store + (B, n) page table -> contiguous (B, n*ps, H, D)
-    per-slot KV view; the SIP-registered kernel when ``cfg.use_pallas``."""
+    """(P, ps, ...) store + (B, n) page table -> contiguous (B, n*ps, ...)
+    per-slot view; the SIP-registered kernel when ``cfg.use_pallas``."""
     if cfg.use_pallas:
         pages = pg_ops.paged_gather(store, pt)
     else:
         pages = jnp.take(store, pt, axis=0)
-    b, n, ps, h, d = pages.shape
-    return pages.reshape(b, n * ps, h, d)
+    b, n, ps, *rest = pages.shape
+    return pages.reshape(b, n * ps, *rest)
+
+
+# --------------------------------------------------------------------- MLA
+def init_mla(key, cfg: ModelConfig):
+    """MLA without a query latent (DeepSeek-V2-Lite): ``wkv_a`` and
+    ``wk_rope`` are ``kv_a_proj_with_mqa`` split at ``kv_lora_rank``,
+    ``wk_b`` and ``wv_b`` are ``kv_b_proj`` split per head at
+    ``qk_nope_dim``."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rp, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 7)
+    return {
+        "wq": nn.param(ks[0], (d, h, nope + rp), ("embed", "heads", None),
+                       scale=d ** -0.5),
+        "wkv_a": nn.param(ks[1], (d, r), ("embed", None), scale=d ** -0.5),
+        "wk_rope": nn.param(ks[2], (d, rp), ("embed", None), scale=d ** -0.5),
+        "kv_norm": nn.param(ks[3], (r,), (None,), init="ones"),
+        "wk_b": nn.param(ks[4], (r, h, nope), (None, "heads", None),
+                         scale=r ** -0.5),
+        "wv_b": nn.param(ks[5], (r, h, vd), (None, "heads", None),
+                         scale=r ** -0.5),
+        "wo": nn.param(ks[6], (h, vd, d),
+                       ("heads", None, "embed"), scale=(h * vd) ** -0.5),
+    }
+
+
+def mla_rope(x: jnp.ndarray, pos: jnp.ndarray, cfg: ModelConfig):
+    """DeepSeek-V2's rotary embedding: the rope dims are de-interleaved
+    (pairs (x0, x1), (x2, x3), ... become x0 x2 ... | x1 x3 ...) and then
+    rotated as halves, with YaRN's frequencies."""
+    d = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2).reshape(x.shape)
+    return rope(x, pos, cfg.rope_theta, cfg.rope_yarn)
+
+
+def mla(p, x: jnp.ndarray, cfg: ModelConfig, *, causal: bool = True,
+        pos_offset: int | jnp.ndarray = 0,
+        cache: dict[str, Any] | None = None, return_cache: bool = False):
+    """Multi-head latent attention.  The cache holds one ``latent`` vector
+    per token: the RMS-normed latent ``c_kv`` (kv_lora_rank,) then the
+    roped key ``k_pe`` (qk_rope_dim,), zero-filled to a whole number of
+    :data:`LANES`, with no head axis: a unit axis there would pad a page
+    store's device layout twofold.  Cached reads (paged or per-slot decode,
+    and chunks) attend in the absorbed form (:func:`_mla_absorbed`);
+    training and whole-prompt prefill expand keys and values per head."""
+    b, s, _ = x.shape
+    pos = _positions(s, pos_offset)
+    dt = x.dtype
+    nope = cfg.qk_nope_dim
+    with jax.named_scope("mla"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+        q_nope, q_pe = q[..., :nope], mla_rope(q[..., nope:], pos, cfg)
+        c_kv = nn.rmsnorm_apply(p["kv_norm"], x @ p["wkv_a"].astype(dt))
+        k_pe = mla_rope((x @ p["wk_rope"].astype(dt))[:, :, None], pos,
+                        cfg)[:, :, 0]
+        new = {"latent": _lane_fill(jnp.concatenate([c_kv, k_pe], -1))}
+        new_cache = None
+        if cache is not None and "pt" in cache:
+            views, new_cache = _paged_update(cache, new, cfg)
+            o = _mla_absorbed(p, q_nope, q_pe, views["latent"], cfg,
+                              causal=causal, kv_len=cache["len"] + s)
+        elif cache is not None:
+            idx = cache["len"]
+            new_cache = dict(_append(cache, new, idx), len=idx + s)
+            o = _mla_absorbed(p, q_nope, q_pe, new_cache["latent"], cfg,
+                              causal=causal, kv_len=idx + s)
+        else:
+            o = _mla_expanded(p, q_nope, q_pe, new["latent"], cfg,
+                              causal=causal)
+            if return_cache:
+                new_cache = dict(new, len=jnp.int32(s))
+        out = jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(dt))
+    if return_cache or cache is not None:
+        return out, new_cache
+    return out
+
+
+def _lane_fill(x: jnp.ndarray) -> jnp.ndarray:
+    """Zero-fill the last dim up to a multiple of :data:`LANES`."""
+    pad = -x.shape[-1] % LANES
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _split_latent(latent: jnp.ndarray, cfg: ModelConfig):
+    """(..., lanes) cached latents -> (c_kv (..., r), k_pe (..., rope))."""
+    r = cfg.kv_lora_rank
+    return latent[..., :r], latent[..., r:r + cfg.qk_rope_dim]
+
+
+def _mla_absorbed(p, q_nope, q_pe, latent, cfg: ModelConfig, *, causal: bool,
+                  kv_len):
+    """Attention in latent space over (B, T, lanes) cached latents:
+    ``q_nope @ W_UK`` takes each query head into the latent, the scores are
+    ``q_lat . c_kv + q_pe . k_pe``, and the output is ``(p . c_kv) @
+    W_UV`` — the cache is never expanded to per-head keys and values.
+    Scores and softmax in float32, as :func:`_sdpa`."""
+    dt = q_nope.dtype
+    f32 = jnp.float32
+    c, k_pe = (a.astype(f32) for a in _split_latent(latent, cfg))
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(f32),
+                       p["wk_b"].astype(f32))
+    sc = (jnp.einsum("bshr,btr->bhst", q_lat, c)
+          + jnp.einsum("bshp,btp->bhst", q_pe.astype(f32), k_pe)) \
+        * cfg.softmax_scale
+    b, s = q_nope.shape[:2]
+    mask = _mask(b, s, c.shape[1], causal=causal, window=None, kv_len=kv_len)
+    w = jax.nn.softmax(jnp.where(mask[:, None], sc, NEG_INF), axis=-1)
+    o_lat = jnp.einsum("bhst,btr->bshr", w, c)
+    return jnp.einsum("bshr,rhv->bshv", o_lat.astype(dt),
+                      p["wv_b"].astype(dt))
+
+
+def _mla_expanded(p, q_nope, q_pe, latent, cfg: ModelConfig, *,
+                  causal: bool):
+    """Attention over keys and values expanded per head from the latent:
+    ``k = [c_kv @ W_UK, k_pe]``, ``v = c_kv @ W_UV``."""
+    dt = q_nope.dtype
+    c, k_pe = _split_latent(latent, cfg)
+    k_pe = k_pe[:, :, None]
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, p["wk_b"].astype(dt))
+    v = jnp.einsum("bsr,rhv->bshv", c, p["wv_b"].astype(dt))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3] + k_pe.shape[-1:])],
+        axis=-1)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    return _sdpa(q, k, v, causal=causal, window=None,
+                 scale=cfg.softmax_scale)
 
 
 def cross_attention(p, x: jnp.ndarray, ctx_kv: tuple[jnp.ndarray, jnp.ndarray],

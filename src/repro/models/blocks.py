@@ -37,9 +37,9 @@ def init_decoder_block(key, cfg: ModelConfig, cross: bool = False):
     return p
 
 
-def _ffn(p, x, cfg: ModelConfig, dropless: bool = False):
+def _ffn(p, x, cfg: ModelConfig, dropless: bool = False, valid=None):
     if cfg.family == "moe":
-        return moe_mod.moe(p["ffn"], x, cfg, dropless=dropless)
+        return moe_mod.moe(p["ffn"], x, cfg, dropless=dropless, valid=valid)
     return mlp_mod.mlp(p["ffn"], x, cfg), ZERO_AUX()
 
 
@@ -47,7 +47,11 @@ def decoder_block(p, x, cfg: ModelConfig, *, causal: bool = True,
                   pos_offset: int | jnp.ndarray = 0,
                   cache: dict[str, Any] | None = None,
                   return_cache: bool = False,
-                  cross_kv: tuple | None = None):
+                  cross_kv: tuple | None = None,
+                  valid: jnp.ndarray | None = None):
+    """Pre-norm attention then FFN -> (x, aux, cache).  ``valid`` (B, S)
+    marks the real tokens (the expert share computes and counts only
+    theirs)."""
     h = nn.rmsnorm_apply(p["ln1"], x)
     if cache is not None or return_cache:
         a, new_cache = attn_mod.attention(p["attn"], h, cfg, causal=causal,
@@ -62,7 +66,8 @@ def decoder_block(p, x, cfg: ModelConfig, *, causal: bool = True,
         hx = nn.rmsnorm_apply(p["ln_x"], x)
         x = x + attn_mod.cross_attention(p["xattn"], hx, cross_kv, cfg)
     h2 = nn.rmsnorm_apply(p["ln2"], x)
-    y, aux = _ffn(p, h2, cfg, dropless=cache is not None)
+    y, aux = _ffn(p, h2, cfg, dropless=cache is not None or return_cache,
+                  valid=valid)
     return x + y, aux, new_cache
 
 

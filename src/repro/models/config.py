@@ -3,6 +3,25 @@
 from __future__ import annotations
 
 import dataclasses
+import math
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` with type "yarn")."""
+
+    factor: float
+    original_max: int              # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def m(self, mscale: float) -> float:
+        """yarn_get_mscale: 0.1 * mscale * ln(factor) + 1 (1 where the
+        factor does not stretch)."""
+        return 0.1 * mscale * math.log(self.factor) + 1.0 \
+            if self.factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -20,6 +39,12 @@ class ModelConfig:
     qk_norm: bool = False
     window: int | None = None      # sliding-window attention (tokens)
     rope_theta: float = 10_000.0
+    rope_yarn: Yarn | None = None  # YaRN frequencies (and MLA softmax scale)
+    # --- latent attention (MLA, DeepSeek-V2; kv_lora_rank > 0) ---------------
+    kv_lora_rank: int = 0          # the cache holds the normed latent c_kv
+    qk_nope_dim: int = 0           # of this width and one roped key of
+    qk_rope_dim: int = 0           # qk_rope_dim per token and layer
+    v_head_dim: int = 0
     tie_embeddings: bool = False
     # --- MoE ---------------------------------------------------------------
     n_experts: int = 0
@@ -27,6 +52,15 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
+    n_shared_experts: int = 0      # shared experts: one SwiGLU of width
+                                   # n_shared_experts * d_ff every token takes
+    norm_topk: bool = True         # renormalize the top-k gates to sum to 1
+    experts_held: int = 0          # expert share: this device holds routed
+    first_expert: int = 0          # experts [first_expert, + experts_held)
+                                   # of n_experts and computes only their
+                                   # part (0 = every expert)
+    first_dense_layers: int = 0    # leading dense SwiGLU layers of width
+    dense_d_ff: int = 0            # dense_d_ff before the MoE stack
     moe_groups: int = 0            # >0: group-local dispatch — tokens are
                                    # routed/sorted/scattered WITHIN each of
                                    # moe_groups batch groups (sharded over
@@ -79,6 +113,31 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        """The routed experts this device holds (``experts_held``, 0 for
+        every one)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def moe_layers(self) -> int:
+        return self.n_layers - self.first_dense_layers \
+            if self.family == "moe" else 0
+
+    @property
+    def softmax_scale(self) -> float:
+        """MLA's score scale: (nope + rope) ** -0.5, times m(mscale_all_dim)
+        squared under YaRN."""
+        s = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        y = self.rope_yarn
+        if y is not None and y.mscale_all_dim:
+            s *= y.m(y.mscale_all_dim) ** 2
+        return s
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -92,6 +151,14 @@ class ModelConfig:
             assert self.n_heads > 0 and self.n_heads % self.n_kv_heads == 0
         if self.family == "moe":
             assert self.n_experts > 0 and 0 < self.top_k <= self.n_experts
+            assert 0 <= self.first_expert and \
+                self.first_expert + self.experts_held <= self.n_experts
+            assert self.first_dense_layers < self.n_layers
+        if self.first_dense_layers:
+            assert self.dense_d_ff > 0
+        if self.mla:
+            assert self.qk_nope_dim > 0 and self.qk_rope_dim % 2 == 0 \
+                and self.v_head_dim > 0
         if self.family in ("ssm", "hybrid"):
             assert self.ssm_state > 0 and self.d_inner % self.ssm_headdim == 0
         if self.family == "hybrid":
@@ -116,6 +183,13 @@ def smoke_variant(cfg: ModelConfig, **overrides) -> ModelConfig:
         vocab=512,
         n_experts=min(cfg.n_experts, 4),
         top_k=min(cfg.top_k, 2),
+        experts_held=min(cfg.experts_held, 4),
+        first_expert=0,
+        dense_d_ff=256 if cfg.dense_d_ff else 0,
+        kv_lora_rank=min(cfg.kv_lora_rank, 32),
+        qk_nope_dim=min(cfg.qk_nope_dim, 16),
+        qk_rope_dim=min(cfg.qk_rope_dim, 8),
+        v_head_dim=min(cfg.v_head_dim, 16),
         ssm_state=min(cfg.ssm_state, 16),
         ssm_headdim=32 if cfg.ssm_state else 64,
         ssm_chunk=16,

@@ -8,6 +8,7 @@ feasible) and the production-standard choice at 1000+-node scale.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any
 
@@ -19,8 +20,22 @@ from repro.models import attention as attn_mod
 from repro.models import blocks
 from repro.models import modules as nn
 from repro.models.config import ModelConfig
+from repro.models.moe import LOSSES
 
 Params = Any
+
+
+def layer_stacks(cfg: ModelConfig):
+    """(params key, config, first layer, layer count) of each scanned
+    decoder stack, in order: the leading dense SwiGLU layers of width
+    ``dense_d_ff`` (DeepSeek's ``first_k_dense_replace``), then the rest."""
+    n = cfg.first_dense_layers
+    if not n:
+        return [("blocks", cfg, 0, cfg.n_layers)]
+    dense = dataclasses.replace(cfg, family="dense", d_ff=cfg.dense_d_ff,
+                                first_dense_layers=0)
+    return [("dense_blocks", dense, 0, n),
+            ("blocks", cfg, n, cfg.n_layers - n)]
 
 
 # ===================================================================== init
@@ -36,8 +51,9 @@ def init_lm(key, cfg: ModelConfig) -> Params:
         p["lm_head"] = nn.param(ks[2], (d, cfg.vocab), ("embed", "vocab"),
                                 scale=d ** -0.5)
     if cfg.family in ("dense", "moe", "vlm"):
-        p["blocks"] = nn.stack_layers(
-            lambda k: blocks.init_decoder_block(k, cfg), ks[3], cfg.n_layers)
+        for (name, c, _, n), k in zip(layer_stacks(cfg), (ks[3], ks[9])):
+            p[name] = nn.stack_layers(
+                lambda k, c=c: blocks.init_decoder_block(k, c), k, n)
     elif cfg.family == "ssm":
         p["blocks"] = nn.stack_layers(
             lambda k: blocks.init_mamba_block(k, cfg), ks[3], cfg.n_layers)
@@ -116,6 +132,29 @@ def _sum_aux(aux):
     return {k: jnp.sum(v) for k, v in aux.items()} if aux else {}
 
 
+def _add_aux(a: dict, b: dict) -> dict:
+    return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
+
+
+def _counts(aux: dict) -> dict:
+    """The aux entries that count work rather than add to the loss."""
+    return {k: v for k, v in aux.items() if k not in LOSSES}
+
+
+def _split_layers(tree, stacks):
+    """Split a per-layer tree's leading axis into one part per stack."""
+    if len(stacks) == 1:
+        return [tree]
+    return [jax.tree.map(lambda a, s=s, n=n: a[s:s + n], tree)
+            for _, _, s, n in stacks]
+
+
+def _concat_layers(parts):
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+
+
 # ============================================================== forward (train)
 def embed_inputs(p, inputs: dict[str, jnp.ndarray], cfg: ModelConfig):
     dt = jnp.dtype(cfg.dtype)
@@ -133,11 +172,13 @@ def forward(p: Params, inputs: dict[str, jnp.ndarray], cfg: ModelConfig):
     x = embed_inputs(p, inputs, cfg)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, lp):
-            h, aux, _ = blocks.decoder_block(lp, h, cfg, causal=True)
-            return h, aux
-        x, aux = _scan(body, x, p["blocks"], cfg)
-        aux = _sum_aux(aux)
+        aux = {}
+        for name, c, _, _ in layer_stacks(cfg):
+            def body(h, lp, c=c):
+                h, a, _ = blocks.decoder_block(lp, h, c, causal=True)
+                return h, a
+            x, a = _scan(body, x, p[name], cfg)
+            aux = _add_aux(aux, _sum_aux(a))
     elif cfg.family == "ssm":
         def body(h, lp):
             h, _ = blocks.mamba_block(lp, h, cfg)
@@ -229,7 +270,8 @@ def loss_fn(p: Params, batch: dict[str, jnp.ndarray], cfg: ModelConfig):
         loss = jnp.sum(token_loss * mask) / denom
     else:
         loss = jnp.mean(token_loss)
-    total = loss + sum(aux.values()) if aux else loss
+    total = loss + sum(v for k, v in aux.items() if k in LOSSES) \
+        if aux else loss
     metrics = {"loss": loss, **{f"aux/{k}": v for k, v in aux.items()}}
     return total, metrics
 
@@ -251,12 +293,15 @@ def prefill(p: Params, inputs: dict[str, jnp.ndarray], cfg: ModelConfig,
     s = x.shape[1]
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, lp):
-            h, _, cache = blocks.decoder_block(lp, h, cfg, causal=True,
-                                               return_cache=True)
-            return h, cache
-        x, caches = _scan(body, x, p["blocks"], cfg)
-        caches = _pad_kv_caches(caches, cfg, max_len)
+        parts = []
+        for name, c, _, _ in layer_stacks(cfg):
+            def body(h, lp, c=c):
+                h, _, cache = blocks.decoder_block(lp, h, c, causal=True,
+                                                   return_cache=True)
+                return h, cache
+            x, part = _scan(body, x, p[name], cfg)
+            parts.append(part)
+        caches = _pad_kv_caches(_concat_layers(parts), cfg, max_len)
     elif cfg.family == "ssm":
         def body(h, lp):
             h, st = blocks.mamba_block(lp, h, cfg, return_state=True)
@@ -280,17 +325,16 @@ def _kv_cache_len(cfg: ModelConfig, max_len: int) -> int:
 def _pad_kv_caches(caches, cfg: ModelConfig, max_len: int):
     m = _kv_cache_len(cfg, max_len)
 
-    def pad(kv):   # (L, B, S, H, D) -> (L, B, m, H, D)
-        l, b, s, h, hd = kv.shape
+    def pad(kv):   # (L, B, S, ...) -> (L, B, m, ...)
+        l, b, s, *rest = kv.shape
         if s == m:
             return jnp.roll(kv, s % m, axis=2) if s % m else kv
         if s > m:   # keep the last window, rolled so slot(p) = p % m
             return jnp.roll(kv[:, :, s - m:], s % m, axis=2)
-        buf = jnp.zeros((l, b, m, h, hd), kv.dtype)
-        return jax.lax.dynamic_update_slice(buf, kv, (0, 0, 0, 0, 0))
+        buf = jnp.zeros((l, b, m, *rest), kv.dtype)
+        return jax.lax.dynamic_update_slice(buf, kv, (0,) * kv.ndim)
 
-    return {"k": pad(caches["k"]), "v": pad(caches["v"]),
-            "len": caches["len"]}
+    return {n: c if n == "len" else pad(c) for n, c in caches.items()}
 
 
 def _hybrid_prefill(p, x, cfg: ModelConfig, max_len: int):
@@ -351,8 +395,8 @@ def decode_step(p: Params, caches, tokens: jnp.ndarray, cfg: ModelConfig, *,
     if cfg.family == "enc_dec":
         return _decode_enc_dec(p, caches, tokens, cfg)
     if cfg.family in ("dense", "moe", "vlm"):
-        logits, caches = decode_tokens(p, caches, tokens[:, None], cfg,
-                                       pt=pt, active=active)
+        logits, caches, _ = decode_tokens(p, caches, tokens[:, None], cfg,
+                                          pt=pt, active=active)
         return logits[:, 0], caches
     if pt is not None:
         raise ValueError(f"paged decode supports attention families "
@@ -382,7 +426,7 @@ def decode_tokens(p: Params, caches, tokens: jnp.ndarray, cfg: ModelConfig, *,
                   n_valid: jnp.ndarray | None = None,
                   embeds: jnp.ndarray | None = None):
     """Cache-advancing forward over ``tokens`` (B, S) for the attention
-    families -> (logits (B, S, vocab), caches).
+    families -> (logits (B, S, vocab), caches, counts).
 
     The S == 1 case is the lockstep decode step; S > 1 is a *chunked
     prefill* step (a prompt chunk pushed through the decode path, so long
@@ -397,7 +441,9 @@ def decode_tokens(p: Params, caches, tokens: jnp.ndarray, cfg: ModelConfig, *,
       position n_valid - 1).
 
     ``embeds`` (B, S, d) replaces the token embedding lookup for
-    embedding-prompt (VLM) chunks.
+    embedding-prompt (VLM) chunks.  ``counts`` sums the layers' work
+    counters over a paged step (the MoE layers' ``moe_local_rows`` and
+    ``moe_rows``; empty for other FFNs and for the slot caches).
     """
     if cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(f"decode_tokens supports attention families "
@@ -408,50 +454,83 @@ def decode_tokens(p: Params, caches, tokens: jnp.ndarray, cfg: ModelConfig, *,
     x = shard(x, "batch", "seq", None)
 
     if pt is not None:
-        x, caches = _paged_layers(p, x, caches, cfg, pt=pt, active=active,
-                                  n_valid=n_valid)
+        x, caches, counts = _paged_layers(p, x, caches, cfg, pt=pt,
+                                          active=active, n_valid=n_valid)
     else:
-        def body(h, xs):
-            lp, cache = xs
-            h, _, cache = blocks.decoder_block(
-                lp, h, cfg, causal=True, pos_offset=cache["len"], cache=cache)
-            return h, cache
+        stacks = layer_stacks(cfg)
+        parts, counts = [], {}
+        for (name, c, _, _), part in zip(stacks,
+                                         _split_layers(caches, stacks)):
+            def body(h, xs, c=c):
+                lp, cache = xs
+                h, _, cache = blocks.decoder_block(
+                    lp, h, c, causal=True, pos_offset=cache["len"],
+                    cache=cache)
+                return h, cache
 
-        x, caches = _scan(body, x, (p["blocks"], caches), cfg)
+            x, part = _scan(body, x, (p[name], part), cfg)
+            parts.append(part)
+        caches = _concat_layers(parts)
     x = nn.rmsnorm_apply(p["ln_f"], x)
     logits = x @ p["lm_head"].astype(x.dtype)
-    return logits, caches
+    return logits, caches, counts
+
+
+def _valid(b: int, s: int, route: dict):
+    """(B, S) real tokens of a paged step: active rows, and positions below
+    ``n_valid``; None where every token is real."""
+    v = None
+    if "active" in route:
+        v = jnp.broadcast_to(route["active"][:, None], (b, s))
+    if "n_valid" in route:
+        m = jnp.broadcast_to(jnp.arange(s)[None, :] < route["n_valid"],
+                             (b, s))
+        v = m if v is None else v & m
+    return v
 
 
 def _paged_layers(p, x, caches, cfg: ModelConfig, **route):
-    """The layer loop over the paged store (L, P, ps, H, D).
+    """The layer loop over the paged store (L, P, ps, ...) -> (x, caches,
+    counts).
 
-    The ``k``/``v`` stores ride the loop's carry as one flat (L*P, ps, H, D)
-    store each, layer ``l``'s page ``i`` at ``l*P + i`` (its trash page at
-    ``l*P``), and each layer's token writes and gather address that store
-    in place through the ``base`` routing key.  Scanned as xs/ys instead,
-    every step would slice each layer's store out for the gather, restack
-    the written slices into a new store and copy it back to the donated
-    buffer.  Only the per-layer ``len`` (L, B) is scanned over.
+    Each page store (``k``/``v``, or MLA's ``latent``) rides the
+    loop's carry as one flat (L*P, ps, ...) store, layer ``l``'s page ``i``
+    at ``l*P + i`` (its trash page at ``l*P``), and each layer's token
+    writes and gather address that store in place through the ``base``
+    routing key.  Scanned as xs/ys instead, every step would slice each
+    layer's store out for the gather, restack the written slices into a new
+    store and copy it back to the donated buffer.  Only the per-layer
+    ``len`` (L, B) is scanned over; the stores ride through each stack of
+    :func:`layer_stacks` in turn.
     """
-    n_layers, n_pages = caches["k"].shape[:2]
+    names = [n for n in caches if n != "len"]
+    n_layers, n_pages = caches[names[0]].shape[:2]
     stores = {n: caches[n].reshape((n_layers * n_pages,) + caches[n].shape[2:])
-              for n in ("k", "v")}
+              for n in names}
     route = {k: v for k, v in route.items() if v is not None}
+    valid = _valid(*x.shape[:2], route) if cfg.family == "moe" else None
+    stacks = layer_stacks(cfg)
+    lens, counts = [], {}
+    for (name, c, first, n), part in zip(stacks,
+                                         _split_layers(caches["len"], stacks)):
+        def body(carry, xs, c=c):
+            h, stores = carry
+            lp, ln, layer = xs
+            cache = dict(stores, len=ln, base=layer * n_pages, **route)
+            h, aux, cache = blocks.decoder_block(
+                lp, h, c, causal=True, pos_offset=ln, cache=cache,
+                valid=valid)
+            return (h, {n: cache[n] for n in stores}), (cache["len"],
+                                                       _counts(aux))
 
-    def body(carry, xs):
-        h, stores = carry
-        lp, lens, layer = xs
-        cache = dict(stores, len=lens, base=layer * n_pages, **route)
-        h, _, cache = blocks.decoder_block(
-            lp, h, cfg, causal=True, pos_offset=lens, cache=cache)
-        return (h, {n: cache[n] for n in stores}), cache["len"]
-
-    layers = jnp.arange(n_layers, dtype=jnp.int32)
-    (x, stores), lens = _scan(body, (x, stores),
-                              (p["blocks"], caches["len"], layers), cfg)
-    return x, dict(caches, len=lens,
-                   **{n: s.reshape(caches[n].shape) for n, s in stores.items()})
+        layers = jnp.arange(first, first + n, dtype=jnp.int32)
+        (x, stores), (ln, cnt) = _scan(body, (x, stores),
+                                       (p[name], part, layers), cfg)
+        lens.append(ln)
+        counts = _add_aux(counts, _sum_aux(cnt))
+    return x, dict(caches, len=_concat_layers(lens),
+                   **{n: s.reshape(caches[n].shape)
+                      for n, s in stores.items()}), counts
 
 
 def _hybrid_decode(p, x, caches, cfg: ModelConfig):
@@ -488,6 +567,9 @@ def cache_logical_axes(cfg: ModelConfig):
     kv = {"k": ("layers", "batch", "kv_seq", "kv_heads", "head_dim"),
           "v": ("layers", "batch", "kv_seq", "kv_heads", "head_dim"),
           "len": ("layers",)}
+    if cfg.mla:
+        kv = {"latent": ("layers", "batch", "kv_seq", None),
+              "len": ("layers",)}
     ssm = {"conv": (None, "batch", None, "conv_ch"),
            "ssd": (None, "batch", "ssm_heads", "ssm_state", None)}
     ssm_g = {"conv": (None, None, "batch", None, "conv_ch"),
@@ -676,9 +758,9 @@ def paged_cache_axes(p: Params, cfg: ModelConfig, max_len: int,
         if not diff:
             return bax
         assert len(diff) == 1, f"ambiguous seq axis for {sa.shape}"
-        # the paged layout assumes the canonical stacked-KV leaf
-        # (layers, batch, seq, heads, head_dim)
-        assert (bax, diff[0]) == (1, 2) and len(sa.shape) == 5, \
+        # the paged layout assumes a stacked cache leaf (layers, batch,
+        # seq, heads, head_dim), or (layers, batch, seq, dim) for a latent
+        assert (bax, diff[0]) == (1, 2) and len(sa.shape) in (4, 5), \
             f"unpageable cache leaf {sa.shape} (batch={bax}, seq={diff[0]})"
         return PAGED_AXIS
 
@@ -690,7 +772,7 @@ def alloc_paged_caches(p: Params, cfg: ModelConfig, capacity: int,
                        example_inputs: dict[str, jnp.ndarray]):
     """Zero-initialized paged decode caches.
 
-    Pageable leaves become (L, num_pages, page_size, H, D) stores shared by
+    Pageable leaves become (L, num_pages, page_size, ...) stores shared by
     every slot (page ids are layer-invariant: a slot's page holds that page's
     positions in every layer).  Non-pageable leaves allocate exactly like
     :func:`alloc_slot_caches` — shared leaves (per-layer ``len``) gain a
@@ -730,9 +812,9 @@ def insert_pages(caches, group_caches, slots, pages, axes):
     def ins(leaf, grp, ax):
         grp = jnp.asarray(grp).astype(leaf.dtype)
         if ax == PAGED_AXIS:
-            l, _, r, h, hd = grp.shape            # (L, G, n_pg*ps, H, D)
+            l, _, r, *rest = grp.shape            # (L, G, n_pg*ps, ...)
             ps = leaf.shape[2]
-            content = grp.reshape(l, g * (r // ps), ps, h, hd)
+            content = grp.reshape(l, g * (r // ps), ps, *rest)
             return leaf.at[:, flat_pages].set(content)
         if ax == SLOT_AXIS_SHARED:
             tiled = jnp.broadcast_to(grp[..., None], grp.shape + (g,))
@@ -797,13 +879,13 @@ def prefill_chunk(p: Params, caches, tokens: jnp.ndarray,
     prefill recompilation to the number of chunk sizes, not prompt lengths);
     ``pt_row`` (1, n_pages) is the slot's page table.  Returns the logits at
     the last valid position ((1, vocab) — only meaningful on the final
-    chunk) and the updated caches.
+    chunk), the updated caches and the step's counts (:func:`decode_tokens`).
     """
     view = slot_view(caches, slot, axes)
-    logits, view = decode_tokens(p, view, tokens, cfg, pt=pt_row,
-                                 n_valid=n_valid, embeds=embeds)
+    logits, view, counts = decode_tokens(p, view, tokens, cfg, pt=pt_row,
+                                         n_valid=n_valid, embeds=embeds)
     last = jax.lax.dynamic_slice_in_dim(logits, n_valid - 1, 1, axis=1)[:, 0]
-    return last, merge_slot(caches, view, slot, axes)
+    return last, merge_slot(caches, view, slot, axes), counts
 
 
 def _decode_enc_dec(p, caches, tokens, cfg: ModelConfig):

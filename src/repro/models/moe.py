@@ -10,7 +10,7 @@ lineage, without a custom grouped-GEMM kernel):
      einsum with experts sharded over the 'model' axis (EP);
   4. gather back, unsort, gate-weight, sum the k copies.
 
-Two dispatch scopes:
+Two dispatch scopes for training's capacity-bounded dispatch:
 
   * global (``cfg.moe_groups == 0``): one argsort/scatter over all tokens.
     Simple, but under GSPMD the scatter into the expert buffer partial-sums
@@ -24,25 +24,51 @@ Two dispatch scopes:
     dispatch collective at all.  This is the EP-friendly layout GShard-style
     systems use.
 
-Aux losses: switch-style load balancing + router z-loss.
+Serving (``dropless``) and every training step of an expert share
+(``cfg.experts_held > 0``, ROADMAP B3) take the share path instead: the
+device holds routed experts [first_expert, first_expert + held) (every
+expert unless ``experts_held`` says otherwise), routes over all
+``n_experts`` at their published width, and computes only its own experts'
+part for the tokens routed to them — dropless, the routed rows sorted by
+held expert through grouped matmuls (``jax.lax.ragged_dot``), with no
+(E, capacity, d) buffer.  What absent experts would add is left out.
+Shared experts (``n_shared_experts``) are one SwiGLU every token takes,
+added on every device alike.
+
+Aux losses: switch-style load balancing + router z-loss; the share path
+also reports ``moe_local_rows`` / ``moe_rows``, the (token, expert) pairs
+routed to the held experts and in all.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 
 from repro.dist.partition import shard
 from repro.dist.tp import tp_allreduce
+from repro.models import mlp as mlp_mod
 from repro.models import modules as nn
 from repro.models.config import ModelConfig
 
+#: the aux entries that are losses (the rest are counts)
+LOSSES = ("load_balance", "router_z")
+
+
+def shared_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The shared experts as one dense MLP of their summed width."""
+    return dataclasses.replace(cfg, d_ff=cfg.n_shared_experts * cfg.d_ff)
+
 
 def init_moe(key, cfg: ModelConfig):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    ks = jax.random.split(key, 4)
-    return {
-        "router": nn.param(ks[0], (d, e), ("embed", "experts"), scale=d ** -0.5),
+    d, f = cfg.d_model, cfg.d_ff
+    e = cfg.held_experts
+    ks = jax.random.split(key, 5)
+    p = {
+        "router": nn.param(ks[0], (d, cfg.n_experts), ("embed", "experts"),
+                           scale=d ** -0.5),
         "w_gate": nn.param(ks[1], (e, d, f), ("experts", "embed", "mlp"),
                            scale=d ** -0.5),
         "w_up": nn.param(ks[2], (e, d, f), ("experts", "embed", "mlp"),
@@ -50,6 +76,9 @@ def init_moe(key, cfg: ModelConfig):
         "w_down": nn.param(ks[3], (e, f, d), ("experts", "mlp", "embed"),
                            scale=f ** -0.5),
     }
+    if cfg.n_shared_experts:
+        p["shared"] = mlp_mod.init_mlp(ks[4], shared_cfg(cfg))
+    return p
 
 
 def _route(p, xt: jnp.ndarray, cfg: ModelConfig):
@@ -58,8 +87,9 @@ def _route(p, xt: jnp.ndarray, cfg: ModelConfig):
     logits = (xt @ p["router"].astype(jnp.float32)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if cfg.norm_topk:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
     density = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], e), axis=0)
     mean_probs = jnp.mean(probs, axis=0)
     aux = {
@@ -92,9 +122,8 @@ def _dispatch_ffn(p, xt: jnp.ndarray, gate_vals, expert_idx,
 
     dt = xt.dtype
     if cfg.mlp_type in ("swiglu", "geglu"):
-        act = jax.nn.silu if cfg.mlp_type == "swiglu" else \
-            (lambda v: jax.nn.gelu(v, approximate=True))
-        h = act(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"].astype(dt))) * \
+        h = _act(cfg)(jnp.einsum("ecd,edf->ecf", buf,
+                                 p["w_gate"].astype(dt))) * \
             jnp.einsum("ecd,edf->ecf", buf, p["w_up"].astype(dt))
     else:
         h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", buf, p["w_up"].astype(dt)),
@@ -111,16 +140,80 @@ def _dispatch_ffn(p, xt: jnp.ndarray, gate_vals, expert_idx,
     return jnp.zeros((t, d), dt).at[sorted_token].add(contrib)
 
 
-def moe(p, x: jnp.ndarray, cfg: ModelConfig, *, dropless: bool = False):
-    """x: (B, S, d) -> (y, aux_losses dict).
+def _act(cfg: ModelConfig):
+    return jax.nn.silu if cfg.mlp_type == "swiglu" else \
+        (lambda v: jax.nn.gelu(v, approximate=True))
 
-    ``dropless=True`` (decode path) sizes every expert for the worst case
-    (capacity = n_tokens per dispatch scope) so no token is ever dropped."""
+
+def _share_ffn(p, xt: jnp.ndarray, gate_vals, expert_idx, cfg: ModelConfig,
+               valid: jnp.ndarray | None):
+    """The held experts' part over (T, d) tokens -> (y, local rows).
+
+    The (token, expert) pairs routed to held experts (and, where ``valid``
+    (T,) is given, from valid tokens) are sorted by held expert; the pairs
+    of absent experts sort after them, outside every group, and
+    ``ragged_dot`` computes no row for them."""
+    t, d = xt.shape
+    k, held = cfg.top_k, cfg.held_experts
+    local = expert_idx.reshape(-1) - cfg.first_expert         # (T*k,)
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine &= jnp.repeat(valid, k)
+    key = jnp.where(mine, local, held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    token = order // k
+    xs = xt[token]
+    dt = xt.dtype
+    if cfg.mlp_type in ("swiglu", "geglu"):
+        h = _act(cfg)(jax.lax.ragged_dot(xs, p["w_gate"].astype(dt), sizes)) \
+            * jax.lax.ragged_dot(xs, p["w_up"].astype(dt), sizes)
+    else:
+        h = jax.nn.gelu(jax.lax.ragged_dot(xs, p["w_up"].astype(dt), sizes),
+                        approximate=True)
+    # manual-TP seam, as in _dispatch_ffn
+    out = tp_allreduce(jax.lax.ragged_dot(h, p["w_down"].astype(dt), sizes))
+    gate = gate_vals.reshape(-1)[order].astype(dt)
+    out = jnp.where(mine[order][:, None], out * gate[:, None], 0.0)
+    return jnp.zeros((t, d), dt).at[token].add(out), jnp.sum(mine)
+
+
+def moe(p, x: jnp.ndarray, cfg: ModelConfig, *, dropless: bool = False,
+        valid: jnp.ndarray | None = None):
+    """x: (B, S, d) -> (y, aux dict).
+
+    ``dropless=True`` (the serving paths) and an expert share compute the
+    held experts' routed rows alone, dropping none (:func:`_share_ffn`);
+    ``valid`` (B, S) then marks the tokens whose rows are computed and
+    counted (padding and idle decode rows are left out).  Training without
+    a share takes the capacity-bounded dispatch."""
+    b, s, d = x.shape
+    with jax.named_scope("moe"):
+        if dropless or cfg.experts_held:
+            t = b * s
+            xt = x.reshape(t, d)
+            gate_vals, expert_idx, aux = _route(p, xt, cfg)
+            vt = None if valid is None else valid.reshape(t)
+            y, local = _share_ffn(p, xt, gate_vals, expert_idx, cfg, vt)
+            y = y.reshape(b, s, d)
+            rows = cfg.top_k * (t if vt is None else jnp.sum(vt))
+            aux = dict(aux, moe_local_rows=local.astype(jnp.int32),
+                       moe_rows=jnp.asarray(rows, jnp.int32))
+        else:
+            y, aux = _capacity_moe(p, x, cfg)
+        if cfg.n_shared_experts:
+            y = y + mlp_mod.mlp(p["shared"], x, shared_cfg(cfg))
+    return y, aux
+
+
+def _capacity_moe(p, x: jnp.ndarray, cfg: ModelConfig):
+    """Every expert over capacity-bounded (E, cap, d) buffers, in one
+    dispatch scope or ``cfg.moe_groups`` of them."""
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     t = b * s
     g = cfg.moe_groups
-    if g and t % g == 0 and t // g >= 1 and not dropless:
+    if g and t % g == 0 and t // g >= 1:
         tg = t // g
         xg = x.reshape(g, tg, d)
         xg = shard(xg, "batch", None, None)       # groups over pod x data
@@ -136,7 +229,6 @@ def moe(p, x: jnp.ndarray, cfg: ModelConfig, *, dropless: bool = False):
 
     xt = x.reshape(t, d)
     gate_vals, expert_idx, aux = _route(p, xt, cfg)
-    # top-k experts are distinct, so capacity t is always dropless
-    cap = t if dropless else min(max(int(t * k / e * cfg.capacity_factor), 1), t)
+    cap = min(max(int(t * k / e * cfg.capacity_factor), 1), t)
     y = _dispatch_ffn(p, xt, gate_vals, expert_idx, cfg, cap)
     return y.reshape(b, s, d), aux

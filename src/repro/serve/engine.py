@@ -209,7 +209,8 @@ class Engine:
                 if done.all():
                     break
             key, sub = jax.random.split(key)
-            token, caches = self._decode(self.params, caches, token, key=sub)
+            token, caches, _ = self._decode(self.params, caches, token,
+                                            key=sub)
         jax.block_until_ready(token)
         self.stats["decode_s"] += time.perf_counter() - t0
         self.stats["tokens_out"] += int(np.size(out))
@@ -271,13 +272,17 @@ class Request:
 #: ``prefill_wait_s`` sums ``prefill_at - submitted_at`` over the
 #: ``prefill_starts`` requests that reached their first prefill; ``host_s``
 #: sums each step's duration less the time it blocked on device results
-#: (its ``.wait`` spans)
+#: (its ``.wait`` spans); ``moe_local_rows`` / ``moe_rows`` sum, over the
+#: decode and chunk steps and the ``cfg.moe_layers`` MoE layers of each, the
+#: (token, expert) pairs routed to the ``cfg.held_experts`` experts held
+#: here and in all (the MoE share path's counts, fetched with each step's
+#: tokens)
 _STAT_KEYS = ("prefill_s", "decode_s", "tokens_out", "prefill_tokens",
               "submitted", "admitted", "completed", "steps", "decode_steps",
               "occupancy_sum", "queue_depth_sum", "prefill_compiles",
               "prefix_hits", "prefix_tokens_saved", "chunk_steps",
               "schedule_swaps", "prefill_wait_s", "prefill_starts",
-              "host_s")
+              "host_s", "moe_local_rows", "moe_rows")
 
 
 @dataclasses.dataclass
@@ -569,10 +574,10 @@ class ContinuousEngine:
 
             def gs_decode(params, caches, token, pt, active, *, key):
                 with partition.mesh_rules(mesh, rules):
-                    tok, caches = _decode_sample_paged(
+                    tok, caches, counts = _decode_sample_paged(
                         params, caches, token, pt, active, cfg=cfg,
                         temperature=scfg.temperature, key=key)
-                    return tok, self._pin_slot_caches(caches)
+                    return tok, self._pin_slot_caches(caches), counts
             self._decode = jax.jit(gs_decode, donate_argnums=(1,))
             self._insert_pages = jax.jit(
                 lambda caches, grp, slots, pages: self._pin_slot_caches(
@@ -586,20 +591,20 @@ class ContinuousEngine:
             def gs_chunk(params, caches, tokens, pt_row, slot, n_valid,
                          embeds=None):
                 with partition.mesh_rules(mesh, rules):
-                    last, caches = M.prefill_chunk(
+                    last, caches, counts = M.prefill_chunk(
                         params, caches, tokens, pt_row, slot, n_valid,
                         cfg=cfg, axes=self._axes, embeds=embeds)
-                    return last, self._pin_slot_caches(caches)
+                    return last, self._pin_slot_caches(caches), counts
             self._chunk = jax.jit(gs_chunk, donate_argnums=(1,))
         else:
             self._prefill = self._build_prefill(scfg.max_len)
 
             def gs_decode(params, caches, token, *, key):
                 with partition.mesh_rules(mesh, rules):
-                    tok, caches = _decode_sample(
+                    tok, caches, counts = _decode_sample(
                         params, caches, token, cfg=cfg,
                         temperature=scfg.temperature, key=key)
-                    return tok, self._pin_slot_caches(caches)
+                    return tok, self._pin_slot_caches(caches), counts
             self._decode = jax.jit(gs_decode, donate_argnums=(1,))
             self._insert = jax.jit(
                 lambda caches, grp, slots: self._pin_slot_caches(
@@ -630,7 +635,7 @@ class ContinuousEngine:
                               _rep(active)),
                     out_specs=(P(), cspecs), check_vma=False)(
                         params, caches, token, pt, active)
-                return _pick(logits, scfg.temperature, key), caches
+                return _pick(logits, scfg.temperature, key), caches, {}
             self._decode = jax.jit(tp_decode, donate_argnums=(1,))
             self._insert_pages = jax.jit(
                 lambda caches, grp, slots, pages: self._pin_slot_caches(
@@ -653,10 +658,10 @@ class ContinuousEngine:
                     with self._seams():
                         return M.prefill_chunk(
                             p, c, t, ptr, s, nv, cfg=cfg, axes=self._axes,
-                            embeds=e[0] if e else None)
-                return shard_map(body, mesh=mesh, in_specs=specs,
-                                 out_specs=(P(), cspecs),
-                                 check_vma=False)(*args)
+                            embeds=e[0] if e else None)[:2]
+                return *shard_map(body, mesh=mesh, in_specs=specs,
+                                  out_specs=(P(), cspecs),
+                                  check_vma=False)(*args), {}
             self._chunk = jax.jit(tp_chunk, donate_argnums=(1,))
         else:
             self._prefill = self._build_prefill(scfg.max_len)
@@ -669,7 +674,7 @@ class ContinuousEngine:
                     body, mesh=mesh, in_specs=(pspecs, cspecs, _rep(token)),
                     out_specs=(P(), cspecs), check_vma=False)(
                         params, caches, token)
-                return _pick(logits, scfg.temperature, key), caches
+                return _pick(logits, scfg.temperature, key), caches, {}
             self._decode = jax.jit(tp_decode, donate_argnums=(1,))
             self._insert = jax.jit(
                 lambda caches, grp, slots: self._pin_slot_caches(
@@ -1032,18 +1037,23 @@ class ContinuousEngine:
         final = task.pos + n >= len(req.prompt)
         with obs_trace.span("serve.prefill_chunk", uid=req.uid, slot=slot,
                             chunk=int(cs), valid=int(n)) as sp:
-            last, self.caches = self._chunk(
+            last, self.caches, counts = self._chunk(
                 self.params, self.caches, jnp.asarray(buf),
                 jnp.asarray(self._pt[slot:slot + 1]), jnp.int32(slot),
                 jnp.int32(n), embeds=embeds)
             if final:
                 self._key, sub = jax.random.split(self._key)
-                tok = int(self._wait(
-                    "serve.prefill_chunk.wait", np.asarray,
-                    _pick(last, self.scfg.temperature, sub))[0])
+                tok, counts = self._wait(
+                    "serve.prefill_chunk.wait", jax.device_get,
+                    (_pick(last, self.scfg.temperature, sub), counts))
+                tok = int(tok[0])
+            elif counts:
+                counts = self._wait("serve.prefill_chunk.wait",
+                                    jax.device_get, counts)
             else:
                 self._wait("serve.prefill_chunk.wait", jax.block_until_ready,
                            last)
+            self._count(counts)
         self._c["prefill_s"].inc(sp.dur_s)
         self._h_prefill.record(sp.dur_s)
         self._c["prefill_tokens"].inc(int(n))
@@ -1098,10 +1108,12 @@ class ContinuousEngine:
         self._maybe_refresh_schedules()
         with obs_trace.span("serve.decode", occupancy=occ) as sp:
             self._key, sub = jax.random.split(self._key)
-            tok, self.caches = self._decode(
+            tok, self.caches, counts = self._decode(
                 self.params, self.caches, jnp.asarray(self.tokens),
                 *map(jnp.asarray, paged), key=sub)
-            tok = self._wait("serve.decode.wait", np.asarray, tok)
+            tok, counts = self._wait("serve.decode.wait", jax.device_get,
+                                     (tok, counts))
+            self._count(counts)
         self._c["decode_s"].inc(sp.dur_s)
         self._c["decode_steps"].inc()
         self._h_decode.record(sp.dur_s)
@@ -1115,6 +1127,11 @@ class ContinuousEngine:
                     continue
                 self.tokens[slot] = int(tok[slot])
                 self._emit(slot, req, int(tok[slot]), finished)
+
+    def _count(self, counts: dict) -> None:
+        """Add a step's fetched work counters to the engine's."""
+        for k, v in counts.items():
+            self._c[k].inc(int(v))
 
     def _emit(self, slot: int, req: Request, tok: int,
               finished: list[Request]) -> None:
@@ -1210,15 +1227,17 @@ class ContinuousEngine:
 
 def _decode_sample(params, caches, token, *, cfg: ModelConfig,
                    temperature: float, key):
+    """-> (tokens, caches, counts); the slot caches count nothing."""
     logits, caches = M.decode_step(params, caches, token, cfg)
-    return _pick(logits, temperature, key), caches
+    return _pick(logits, temperature, key), caches, {}
 
 
 def _decode_sample_paged(params, caches, token, pt, active, *,
                          cfg: ModelConfig, temperature: float, key):
-    logits, caches = M.decode_step(params, caches, token, cfg, pt=pt,
-                                   active=active)
-    return _pick(logits, temperature, key), caches
+    """-> (tokens, caches, the step's counts: ``M.decode_tokens``)."""
+    logits, caches, counts = M.decode_tokens(params, caches, token[:, None],
+                                             cfg, pt=pt, active=active)
+    return _pick(logits[:, 0], temperature, key), caches, counts
 
 
 def _pick(logits, temperature: float, key):

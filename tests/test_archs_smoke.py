@@ -51,6 +51,7 @@ class TestArchSmoke:
             "internlm2-20b": (48, 6144, 48, 8, 16384, 92544),
             "qwen3-1.7b": (28, 2048, 16, 8, 6144, 151936),
             "h2o-danube-1.8b": (24, 2560, 32, 8, 6912, 32000),
+            "deepseek-v2-lite": (27, 2048, 16, 16, 1408, 102400),
         }[arch]
         got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                cfg.d_ff, cfg.vocab)

@@ -411,9 +411,9 @@ class TestFlatStoreLayerLoop:
 
         one = {n: jnp.asarray(dense[n][:, slot:slot + 1]) for n in ("k", "v")}
         one["len"] = jnp.asarray(lens[:, slot])     # (L,) scalar per layer
-        d_logits, d_new = jax.jit(functools.partial(M.decode_tokens, cfg=cfg))(
-            params3, one, chunk)
-        last, new = jax.jit(functools.partial(M.prefill_chunk, cfg=cfg,
+        d_logits, d_new, _ = jax.jit(
+            functools.partial(M.decode_tokens, cfg=cfg))(params3, one, chunk)
+        last, new, _ = jax.jit(functools.partial(M.prefill_chunk, cfg=cfg,
                                               axes=axes),
                             donate_argnums=(1,))(
             params3, caches, chunk, jnp.asarray(pt[slot:slot + 1]), slot,
