@@ -9,8 +9,8 @@ engine configuration, :func:`serve_targets` maps each live key to the SIP
 kernel the engine's hot path actually dispatches for it, with a
 ``make_args`` matching the observed shape.
 
-Keys with no tunable kernel behind them (submit records, decode without the
-paged gather) map to None and the service skips them.
+Keys with no tunable kernel behind them (submit records, decode outside
+paged serving) map to None and the service skips them.
 """
 
 from __future__ import annotations
@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.registry import Workload
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.paged_attention import ops as pg_ops
+from repro.models import attention as A
 from repro.models.config import ModelConfig
 from repro.obs.recorder import WorkloadKey
 from repro.serve.engine import ServeConfig
@@ -63,9 +64,14 @@ def serve_targets(cfg: ModelConfig, scfg: ServeConfig
       and the model's head geometry.  Under paged serving the engine
       prefills at page-rounded lengths, so the key's prompt_len is already
       the dispatched ``sq``.
-    * ``decode`` keys -> the ``paged_gather`` kernel (paged serving's
-      page-table-indirect cache read) at the pool geometry; contiguous-mode
-      decode has no SIP kernel on its path, so those keys are skipped.
+    * ``decode`` keys -> the kernel paged serving's decode reads its
+      cache with, at the geometry it dispatches: under ``cfg.use_pallas``
+      the fused ``paged_decode_attention`` (kv heads x query group over
+      the K/V stores, or MLA's one latent "head" of ``n_heads`` queries
+      over the lane-filled latent store), else — and for padded heads,
+      which keep the gathered view — the ``paged_gather`` kernel at the
+      pool geometry; contiguous-mode decode has no SIP kernel on its path,
+      so those keys are skipped.
     * anything else (``submit`` bookkeeping) -> None.
     """
     hd = cfg.hd
@@ -73,6 +79,12 @@ def serve_targets(cfg: ModelConfig, scfg: ServeConfig
     n_slot_pages = -(-scfg.max_len // ps)
     num_pages = (scfg.num_pages if scfg.num_pages is not None
                  else scfg.capacity * n_slot_pages + 1)
+    fused = cfg.use_pallas and A.kv_head_map(cfg) is None
+    if cfg.mla:
+        hkv = 1
+        d = -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // A.LANES) * A.LANES
+    else:
+        hkv, d = cfg.n_kv_heads, hd
 
     def target_for(key: WorkloadKey) -> TuneTarget | None:
         if key.kind == "prefill" and key.prompt_len >= 1:
@@ -82,6 +94,12 @@ def serve_targets(cfg: ModelConfig, scfg: ServeConfig
             return TuneTarget(name, Workload(name=key.name,
                                              make_args=make_args,
                                              suites=("live",)))
+        if key.kind == "decode" and scfg.paged and fused:
+            make_args = pg_ops.decode_args(
+                num_pages, ps, hkv, cfg.n_heads // hkv, d, key.batch,
+                n_slot_pages, shared_kv=cfg.mla, dtype=key.dtype)
+            return TuneTarget(pg_ops.DECODE_NAME, Workload(
+                name=key.name, make_args=make_args, suites=("live",)))
         if key.kind == "decode" and scfg.paged:
             make_args = _gather_args(num_pages, ps, cfg.n_kv_heads, hd,
                                      key.batch, n_slot_pages, key.dtype)

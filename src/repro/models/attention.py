@@ -240,7 +240,12 @@ def attention(p, x: jnp.ndarray, cfg: ModelConfig, *,
     kv_idx = kv_head_map(cfg)
 
     new_cache = None
-    if cache is not None and "pt" in cache:     # paged decode / chunk prefill
+    if cache is not None and "pt" in cache and kv_idx is None \
+            and _fused_decode(cache, cfg, s):
+        new_cache = _paged_write(cache, {"k": k, "v": v})
+        o = _paged_decode(q, cache, new_cache["k"], new_cache["v"],
+                          scale=cfg.hd ** -0.5)
+    elif cache is not None and "pt" in cache:   # paged chunk (or jnp decode)
         views, new_cache = _paged_update(cache, {"k": k, "v": v}, cfg)
         o = _sdpa(q, views["k"], views["v"], causal=causal, window=None,
                   kv_len=cache["len"] + s, kv_idx=kv_idx)
@@ -298,7 +303,23 @@ def _paged_update(cache: dict[str, Any], new: dict[str, jnp.ndarray],
                   cfg: ModelConfig):
     """Page-table-indirect cache write + read (continuous batching over a
     paged store).  Returns each store's gathered per-slot view and the
-    updated cache.
+    updated cache (:func:`_paged_write`).
+
+    Reads gather the slot's pages back into a contiguous (B, n*ps, ...)
+    view (the SIP-tuned ``paged_gather`` kernel under ``cfg.use_pallas``)
+    for the per-slot masked attention, which reads keys below ``len + s``.
+    Sliding-window archs keep the dense ring buffer (the engine gates
+    paging to window=None families).
+    """
+    new_cache = _paged_write(cache, new)
+    pt = cache["pt"] + cache["base"]
+    views = {n: _gather_pages(new_cache[n], pt, cfg) for n in new}
+    return views, new_cache
+
+
+def _paged_write(cache: dict[str, Any], new: dict[str, jnp.ndarray]):
+    """Scatter ``new``'s tokens into the paged stores -> the updated cache
+    (the stores and the advanced ``len``).
 
     ``cache`` holds the flat page stores named in ``new`` (``k``/``v`` of
     (L*P, ps, H, D), or MLA's ``latent`` of (L*P, ps, D)), each over
@@ -313,16 +334,10 @@ def _paged_update(cache: dict[str, Any], new: dict[str, jnp.ndarray],
     ``len + n_valid`` positions are never read because the engine reserves
     worst-case pages per slot and reads are bounded by ``len``).
 
-    Writes scatter each token at (base + pt[b, pos // ps], pos % ps);
-    reads gather the slot's pages back into a contiguous (B, n*ps, ...)
-    view (the SIP-tuned ``paged_gather`` kernel under ``cfg.use_pallas``)
-    for the per-slot masked attention, which reads keys below ``len + s``.
-    Sliding-window archs keep the dense ring buffer (the engine gates
-    paging to window=None families).
+    Writes scatter each token at (base + pt[b, pos // ps], pos % ps).
     """
     idx = cache["len"]
     pt = cache["pt"]
-    base = cache["base"]
     active = cache.get("active")
     n_valid = cache.get("n_valid")
     b, s = next(iter(new.values())).shape[:2]
@@ -340,18 +355,40 @@ def _paged_update(cache: dict[str, Any], new: dict[str, jnp.ndarray],
     page_ids = jnp.where(page_slot < n_pages, page_ids, 0)
     if active is not None:
         page_ids = jnp.where(active[:, None], page_ids, 0)  # trash page
-    page_ids = page_ids + base
+    page_ids = page_ids + cache["base"]
     offs = pos % ps
     stores = {n: cache[n].at[page_ids, offs].set(x.astype(cache[n].dtype))
               for n, x in new.items()}
 
-    pt = pt + base
-    views = {n: _gather_pages(st, pt, cfg) for n, st in stores.items()}
-
     adv = s if n_valid is None else n_valid
     if active is not None:
         adv = jnp.where(active, adv, 0)
-    return views, dict(stores, len=idx + adv)
+    return dict(stores, len=idx + adv)
+
+
+def _fused_decode(cache: dict[str, Any], cfg: ModelConfig, s: int) -> bool:
+    """Whether a paged read is a decode step's that the fused kernel
+    serves: one token a row, compiled kernels on."""
+    return cfg.use_pallas and s == 1 and "n_valid" not in cache
+
+
+def _paged_decode(q, cache: dict[str, Any], keys, values, *, scale: float):
+    """Decode attention over each row's live pages, read in place by the
+    SIP-registered ``paged_decode_attention`` kernel: (B, 1, Hkv*G, D)
+    queries over the ``keys`` store (and the ``values`` store, or None
+    where the key page is the value page), already holding this step's
+    token -> (B, 1, Hkv*G, Dv) in the store's dtype.  A row that is not
+    ``active`` reads nothing."""
+    b, _, h, d = q.shape
+    hkv = 1 if keys.ndim == 3 else keys.shape[2]
+    lens = cache["len"] + 1
+    if "active" in cache:
+        lens = jnp.where(cache["active"], lens, 0)
+    o = pg_ops.paged_decode_attention(
+        q.reshape(b, hkv, h // hkv, d), keys, cache["pt"], cache["base"],
+        lens, jnp.asarray(scale, jnp.float32),
+        *(() if values is None else (values,)))
+    return o.reshape(b, 1, h, o.shape[-1])
 
 
 def _gather_pages(store, pt, cfg: ModelConfig):
@@ -420,7 +457,12 @@ def mla(p, x: jnp.ndarray, cfg: ModelConfig, *, causal: bool = True,
                         cfg)[:, :, 0]
         new = {"latent": _lane_fill(jnp.concatenate([c_kv, k_pe], -1))}
         new_cache = None
-        if cache is not None and "pt" in cache:
+        if cache is not None and "pt" in cache and \
+                _fused_decode(cache, cfg, s):
+            new_cache = _paged_write(cache, new)
+            o = _mla_paged_decode(p, q_nope, q_pe, cache,
+                                  new_cache["latent"], cfg)
+        elif cache is not None and "pt" in cache:
             views, new_cache = _paged_update(cache, new, cfg)
             o = _mla_absorbed(p, q_nope, q_pe, views["latent"], cfg,
                               causal=causal, kv_len=cache["len"] + s)
@@ -472,6 +514,21 @@ def _mla_absorbed(p, q_nope, q_pe, latent, cfg: ModelConfig, *, causal: bool,
     w = jax.nn.softmax(jnp.where(mask[:, None], sc, NEG_INF), axis=-1)
     o_lat = jnp.einsum("bhst,btr->bshr", w, c)
     return jnp.einsum("bshr,rhv->bshv", o_lat.astype(dt),
+                      p["wv_b"].astype(dt))
+
+
+def _mla_paged_decode(p, q_nope, q_pe, cache, latent, cfg: ModelConfig):
+    """The absorbed form's decode over the paged latent store: multi-query
+    attention with one kv "head", the latent, as both key and value.  The
+    query is ``[q_lat, q_pe, 0]`` over the latent's lanes; the first
+    ``kv_lora_rank`` lanes of the output are ``p . c_kv``."""
+    dt = q_nope.dtype
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(f32),
+                       p["wk_b"].astype(f32))
+    q = _lane_fill(jnp.concatenate([q_lat, q_pe.astype(f32)], -1))
+    o = _paged_decode(q, cache, latent, None, scale=cfg.softmax_scale)
+    return jnp.einsum("bshr,rhv->bshv", o[..., :cfg.kv_lora_rank].astype(dt),
                       p["wv_b"].astype(dt))
 
 

@@ -276,13 +276,18 @@ class Request:
 #: decode and chunk steps and the ``cfg.moe_layers`` MoE layers of each, the
 #: (token, expert) pairs routed to the ``cfg.held_experts`` experts held
 #: here and in all (the MoE share path's counts, fetched with each step's
-#: tokens)
+#: tokens); ``decode_pages_read`` sums, over paged decode steps, the pages
+#: the decoding rows' attention reads (each row's live pages, this step's
+#: token included, in every paged layer) and ``decode_table_pages`` the
+#: pages of every row's whole table in every paged layer: their ratio is
+#: the share of the tables the decode read touches
 _STAT_KEYS = ("prefill_s", "decode_s", "tokens_out", "prefill_tokens",
               "submitted", "admitted", "completed", "steps", "decode_steps",
               "occupancy_sum", "queue_depth_sum", "prefill_compiles",
               "prefix_hits", "prefix_tokens_saved", "chunk_steps",
               "schedule_swaps", "prefill_wait_s", "prefill_starts",
-              "host_s", "moe_local_rows", "moe_rows")
+              "host_s", "moe_local_rows", "moe_rows", "decode_pages_read",
+              "decode_table_pages")
 
 
 @dataclasses.dataclass
@@ -405,6 +410,9 @@ class ContinuousEngine:
             self.caches, self._axes = M.alloc_paged_caches(
                 params, cfg, scfg.capacity, scfg.max_len, ps, num_pages,
                 example_inputs)
+            #: layers each page-table entry addresses a page in
+            self._paged_layers = next(
+                v for n, v in self.caches.items() if n != "len").shape[0]
             # host-side page tables, (capacity, n_slot_pages) int32 — passed
             # into every paged dispatch; a slot's row is zeroed while free
             self._pt = np.zeros((scfg.capacity, self._n_slot_pages), np.int32)
@@ -1099,6 +1107,11 @@ class ContinuousEngine:
             return
         active = np.zeros(self.capacity, bool)
         active[decoding] = True
+        ps, layers = self.pages.page_size, self._paged_layers
+        live = sum(-(-(len(req.prompt) + len(req.tokens)) // ps)
+                   for s, req in self.pool.held() if active[s])
+        self._c["decode_pages_read"].inc(live * layers)
+        self._c["decode_table_pages"].inc(self._pt.size * layers)
         self._decode_batch(len(decoding), finished, self._pt, active)
 
     def _decode_batch(self, occ: int, finished: list[Request],

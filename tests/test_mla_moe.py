@@ -197,6 +197,39 @@ def test_paged_serving_matches_the_reference(use_pallas):
             assert _rel(got[s][r], want[s][i]) < 2e-4, (s, r)
 
 
+def test_fused_decode_matches_the_gathered_read():
+    """Paged absorbed decode through the fused kernel (``use_pallas``)
+    against the gathered latent view through ``_mla_absorbed``, on the
+    same noisy latent store, with an idle row: the same logits to float32
+    rounding, and the same tokens."""
+    spec = _spec()
+    p = _params(spec)
+    ex = {"tokens": np.zeros((1, 8), np.int32)}
+    n_pg = MAX_LEN // PS
+    rng = np.random.default_rng(6)
+    pt = jnp.asarray(rng.permutation(np.arange(1, 3 * n_pg + 1))
+                     .reshape(3, n_pg).astype(np.int32))
+    caches, _ = M.alloc_paged_caches(p, _cfg(spec), 3, MAX_LEN, PS,
+                                     3 * n_pg + 1, ex)
+    noise = rng.standard_normal(caches["latent"].shape).astype(np.float32)
+    lens = np.tile([37, 0, 8], (spec.layers, 1)).astype(np.int32)
+    out = {}
+    for use_pallas in (False, True):
+        cfg = _cfg(spec, use_pallas=use_pallas)
+        caches = {"latent": jnp.asarray(noise), "len": jnp.asarray(lens)}
+        logits, new = jax.jit(functools.partial(M.decode_step, cfg=cfg))(
+            p, caches, jnp.asarray([3, 4, 5], jnp.int32), pt=pt,
+            active=jnp.asarray([True, False, True]))
+        out[use_pallas] = (np.asarray(logits)[[0, 2]],
+                           {n: np.asarray(v) for n, v in new.items()})
+    (want, w_new), (got, g_new) = out[False], out[True]
+    assert _rel(got, want) < 1e-5
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    np.testing.assert_array_equal(g_new["len"], w_new["len"])
+    # layer 0's latent comes before any attention: written bit for bit
+    np.testing.assert_array_equal(g_new["latent"][0], w_new["latent"][0])
+
+
 # ------------------------------------------------------- the expert share
 def _moe_layer(spec: R.Spec):
     """Layer 1's MoE weights for ``spec``'s share, in float32."""

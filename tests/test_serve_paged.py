@@ -301,6 +301,22 @@ class TestGating:
                           pt=np.zeros((8, 2), np.int32))
 
 
+def test_engine_counts_the_decode_read(params):
+    """Each paged decode step counts the pages its rows' attention reads,
+    each decoding row's live pages with this step's token, and the pages
+    of every row's whole table, in every layer."""
+    eng = ContinuousEngine(params, CFG, _paged_cfg())
+    rng = np.random.default_rng(11)
+    eng.submit(rng.integers(1, CFG.vocab, 5).astype(np.int32), 4)
+    eng.submit(rng.integers(1, CFG.vocab, 13).astype(np.int32), 3)
+    eng.run(max_steps=100)
+    st = eng.stats
+    # reads of 6, 7, 8 tokens (a page each) and of 14, 15 (two pages each)
+    assert st["decode_pages_read"] == (3 + 4) * CFG.n_layers
+    assert st["decode_table_pages"] == \
+        st["decode_steps"] * 3 * (MAX_LEN // 8) * CFG.n_layers
+
+
 # ============================================= the layer loop's flat store
 # Three layers, three slots of four 8-token pages over a 13-page pool (page 0
 # is each layer's trash page): the paged decode and chunk steps against the
@@ -382,13 +398,26 @@ class TestFlatStoreLayerLoop:
             active=jnp.asarray(active))
 
         rows = np.flatnonzero(active)
-        np.testing.assert_array_equal(np.asarray(logits)[rows],
-                                      np.asarray(d_logits)[rows])
+        got, want = np.asarray(logits)[rows], np.asarray(d_logits)[rows]
+        if use_pallas:
+            # the fused decode kernel sums keys in blocks of pages with an
+            # online softmax: the same tokens, logits to float32 rounding
+            np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
         for n in ("k", "v"):
             want = _pages_of({"init": init[n], "new": np.asarray(d_new[n])},
                              pt, rows)
             got = np.asarray(new[n])
-            _assert_layers_equal(got, want)
+            if use_pallas:
+                # layer 0's keys and values come before any attention;
+                # later layers' carry the kernel's rounding
+                _assert_layers_equal(got[:1], want[:1])
+                np.testing.assert_allclose(got[:, 1:], want[:, 1:],
+                                           rtol=1e-5, atol=1e-5)
+            else:
+                _assert_layers_equal(got, want)
             # the idle row (len 0) wrote its token into offset 0 of every
             # layer's own trash page
             for layer in range(cfg.n_layers):
@@ -432,21 +461,24 @@ class TestFlatStoreLayerLoop:
 
 
 def test_served_gather_keys_the_adapters_schedule(params3, monkeypatch):
-    """The autotune adapter tunes ``paged_gather`` on one layer's pool; the
-    layer loop gathers from the flat store of every layer.  Both resolve
-    the same schedule key."""
+    """The autotune adapter tunes the decode read the paged decode step
+    dispatches, ``paged_decode_attention``, on one layer's pool; the layer
+    loop reads the flat store of every layer.  Both resolve the same
+    schedule key."""
     from repro.autotune.adapters import serve_targets
+    from repro.core.registry import registry
     from repro.kernels.paged_attention import ops as pg_ops
     from repro.obs.recorder import WorkloadKey
 
     cfg = dataclasses.replace(CFG3, use_pallas=True)
-    kern, real, served = pg_ops.kernel(), pg_ops.paged_gather, []
+    kern, real, served = (registry.get(pg_ops.DECODE_NAME),
+                          pg_ops.paged_decode_attention, [])
 
-    def gather(store, table):
-        served.append(kern.static_of(store, table))
-        return real(store, table)
+    def read(*args):
+        served.append(kern.static_of(*args))
+        return real(*args)
 
-    monkeypatch.setattr(pg_ops, "paged_gather", gather)
+    monkeypatch.setattr(pg_ops, "paged_decode_attention", read)
     caches, _, pt, _ = _store_and_dense(params3, cfg)
     M.decode_step(params3, caches, jnp.zeros((CAP,), jnp.int32), cfg,
                   pt=jnp.asarray(pt), active=jnp.ones((CAP,), bool))
@@ -454,9 +486,11 @@ def test_served_gather_keys_the_adapters_schedule(params3, monkeypatch):
                        page_size=PS, num_pages=POOL)
     target = serve_targets(cfg, scfg)(WorkloadKey("decode", 0, CAP,
                                                   "float32"))
+    assert target.kernel == pg_ops.DECODE_NAME
     tuned = kern.static_of(*target.workload.make_args(
         np.random.default_rng(0)))
-    assert served and all(s == tuned for s in served), (served, tuned)
+    assert served
+    assert all(s == tuned for s in served), (served, tuned)
 
 
 #: an HLO instruction's result dims and opcode: `%x = f32[3,13]{1,0} copy(`
@@ -489,6 +523,33 @@ def test_paged_programs_do_not_restack_the_store(params3, program,
            if m.group(1) in stores
            and m.group(2) in ("dynamic-update-slice", "concatenate", "copy")]
     assert not bad, "\n".join(bad)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_fused_decode_reads_the_store_in_place(params3, scan_layers):
+    """Under ``use_pallas`` the compiled paged decode program (cache
+    donated) reads the page stores through the fused kernel: it builds no
+    gathered (B, n*ps, H, D) view, nor its (B, n, ps, H, D) page stack,
+    and copies or rebuilds no whole store."""
+    cfg = dataclasses.replace(CFG3, scan_layers=scan_layers,
+                              use_pallas=True)
+    caches, _, pt, _ = _store_and_dense(params3, cfg)
+
+    def fn(p, c, t, pt_, a):
+        return M.decode_step(p, c, t, cfg, pt=pt_, active=a)
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        params3, caches, jnp.zeros((CAP,), jnp.int32), jnp.asarray(pt),
+        jnp.ones((CAP,), bool)).compile().as_text()
+    l, pool, ps, h, d = caches["k"].shape
+    views = {f"{CAP},{N_PG * ps},{h},{d}", f"{CAP},{N_PG},{ps},{h},{d}"}
+    stores = {f"{l},{pool},{ps},{h},{d}", f"{l * pool},{ps},{h},{d}"}
+    ops = [(m.group(1), m.group(2), m.group(0))
+           for m in _HLO_OP.finditer(hlo)]
+    assert not [o for dims, _, o in ops if dims in views]
+    assert not [o for dims, op, o in ops if dims in stores and op in
+                ("dynamic-update-slice", "concatenate", "copy")]
+    assert any(dims == f"{l * pool},{ps},{h},{d}" and op == "scatter"
+               for dims, op, _ in ops), "the token is written in place"
 
 
 # ================================================================ tracing

@@ -94,6 +94,40 @@ def test_paged_gather_default_schedule_compiles(one_chip, page):
                                **knobs), store, pt)
 
 
+@pytest.mark.parametrize("ppb", [8, 16, 32, 64])
+@pytest.mark.parametrize("page,g", [((8, 128), 2), ((640,), 16)],
+                         ids=["gqa", "latent640"])
+def test_paged_decode_attention_schedules_compile(one_chip, page, g, ppb):
+    """The fused decode read at the serving cells' geometry, at each block
+    size its space offers from 8 pages up: qwen3's 8 kv heads of 128 (K
+    and V stores) and deepseek's one 640-lane latent "head" of 16 queries
+    (the key page is the value page), bf16 stores of 16-token pages; a
+    table width no block size divides."""
+    hkv, d = page if len(page) == 2 else (1, page[0])
+    shared = len(page) == 1
+    static = dict(b=16, hkv=hkv, g=g, d=d, dv=d, ps=16, n=37,
+                  shared_kv=shared, dtype="bfloat16")
+    assert ppb in pg_ops.decode_space(**static).knobs[0].choices
+    knobs = {"pages_per_block": ppb}
+    store = jax.ShapeDtypeStruct((273, 16, *page), jnp.bfloat16,
+                                 sharding=one_chip)
+    q = jax.ShapeDtypeStruct((16, hkv, g, d),
+                             jnp.float32 if shared else jnp.bfloat16,
+                             sharding=one_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (q, store, arg((16, 37)), arg(()), arg((16,)),
+            arg((), jnp.float32)) + (() if shared else (store,))
+    compiled = _compile(functools.partial(pg_kernel.paged_decode_attention,
+                                          interpret=False, **knobs), *args)
+    # the stores are read where they lie
+    assert not [m.group(0) for m in _HLO_OP.finditer(compiled.as_text())
+                if m.group(1).startswith("273,16,")
+                and m.group(2) in ("copy", "transpose", "fusion")]
+
+
 #: the paged cache of the qwen3-1.7b step compiles: slots, max_len, page
 CAP, MAX_LEN, PS = 8, 544, 16
 N_PG = -(-MAX_LEN // PS)
@@ -174,6 +208,17 @@ def _compile_step(program, cfg, shapes, caches, axes, arg, cap, n_pg):
     return compiled
 
 
+def _gathered_views(compiled, caches, cap, n_pg) -> list[str]:
+    """Ops that build a slot batch's whole-table view of a page store:
+    (B, n, ps, ...) pages or (B, n*ps, ...) positions."""
+    store = next(a for n, a in caches.items() if n != "len")
+    page = ",".join(map(str, store.shape[3:]))
+    ps = store.shape[2]
+    views = {f"{cap},{n_pg},{ps},{page}", f"{cap},{n_pg * ps},{page}"}
+    return [m.group(0) for m in _HLO_OP.finditer(compiled.as_text())
+            if m.group(1) in views]
+
+
 def _store_rewrites(compiled, caches) -> list[str]:
     """Ops that rebuild or copy a whole page store."""
     stores = [a for n, a in caches.items() if n != "len"]
@@ -194,6 +239,26 @@ def test_qwen3_paged_steps_write_the_store_in_place(one_chip, monkeypatch,
     compiled = _compile_step(program, cfg, shapes, caches, axes, arg, CAP,
                              N_PG)
     bad = _store_rewrites(compiled, caches)
+    assert not bad, "\n".join(bad)
+
+
+@pytest.mark.parametrize("family", ["qwen3", "deepseek"])
+def test_paged_decode_steps_read_no_gathered_view(one_chip, monkeypatch,
+                                                  family):
+    """On the chip's compiler, the engine's paged decode step reads each
+    row's pages through the fused kernel: no op builds the slots' whole
+    tables as a view, and no op copies a whole store."""
+    if family == "qwen3":
+        cfg, shapes, caches, axes, arg = _qwen3_paged(one_chip, monkeypatch)
+        cap, n_pg = CAP, N_PG
+    else:
+        cfg, shapes, caches, axes, arg = _deepseek_paged(one_chip,
+                                                         monkeypatch)
+        cap, n_pg = DS_CAP, DS_N_PG
+    compiled = _compile_step("decode", cfg, shapes, caches, axes, arg, cap,
+                             n_pg)
+    bad = _gathered_views(compiled, caches, cap, n_pg) + \
+        _store_rewrites(compiled, caches)
     assert not bad, "\n".join(bad)
 
 
